@@ -33,17 +33,16 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 
 
 def tp_shard_map(fn, mesh, in_specs, out_specs):
     """shard_map a kernel body over the serving mesh.
 
-    ``check_rep=False``: the bodies are opaque Pallas calls (or their
+    ``check_vma=False``: the bodies are opaque Pallas calls (or their
     interpret twins) — replication checking cannot see through them, and
     every output is explicitly spec'd anyway."""
-    return shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 

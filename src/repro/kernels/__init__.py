@@ -1,15 +1,15 @@
 """Pallas TPU kernels for the paper's compute hot-spots.
 
 sparse_delta / fused_linear — the paper's "fused scatter-add" bypass path
-(footnote 2), TPU-adapted as lane gathers (DESIGN.md §2.2);
+(footnote 2), TPU-adapted as tile-densified MXU products (DESIGN.md §2.2);
 sparse_delta_batched — the multi-tenant serving variant: N stacked adapters
 selected per batch row (DESIGN.md §7);
 topk_select — Alg. 1 Phase 1 offline selection;
 flash_attention — fused online-softmax attention (added from the §Perf
 memory-term analysis).
 
-ops.py holds the jit'd public wrappers with backend dispatch
-(jnp | pallas | pallas_interpret); ref.py the pure-jnp oracles.
+ops.py holds the jit'd public wrappers with backend dispatch (pallas on a
+TPU, jnp elsewhere, pallas_interpret in tests); ref.py the pure-jnp oracles.
 """
 
 from repro.kernels import ops, ref

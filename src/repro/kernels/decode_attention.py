@@ -26,6 +26,12 @@ the grid is (slot, kv-head, page) and the page dimension accumulates the
 same online-softmax scratch as the dense-slot kernel. Sentinel table
 entries (unallocated pages) clamp to a resident block; their columns sit
 past the slot's frontier and mask to zero.
+
+Layout for the chip's (8, 128) tiling: KV is viewed as ``(…, Hkv·hd)``
+(:func:`lane_view`), so a block takes one kv head as ``hd`` = 128 lanes
+instead of a width-1 slice of the Hkv axis; per-slot valid lengths, the
+(flattened) block table and int8 scales ride in SMEM as scalar-prefetch
+operands. The online-softmax update is shared with the prefill kernel.
 """
 
 from __future__ import annotations
@@ -37,99 +43,105 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 _NEG = -1e30
 
 
+def lane_view(kv: jax.Array) -> jax.Array:
+    """(…, Hkv, hd) -> (…, Hkv·hd): a free reshape that lets a KV block be
+    ``(…, rows, hd)`` at lane offset ``h·hd``. A block of width 1 on the
+    kv-head axis would sit under the chip's (8, 128) tiling."""
+    return kv.reshape(*kv.shape[:-2], kv.shape[-2] * kv.shape[-1])
+
+
+def softmax_init(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def softmax_step(q, kb, vb, valid, m_ref, l_ref, acc_ref, *, scale, ks=None, vs=None):
+    """One online-softmax update of the f32 (m, l, acc) scratch.
+
+    q (R, hd) f32 against a (cols, hd) KV tile; ``valid`` (R, cols) masks
+    columns. ``ks``/``vs`` are int8-cache dequant scales that broadcast
+    against the (R, cols) scores — a scalar per page or a (1, cols) row —
+    applied to the scores and to the probabilities before the PV product,
+    which is the same as dequantizing the tile."""
+    s = jax.lax.dot_general(
+        q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+    if ks is not None:
+        s = s * ks
+    s = jnp.where(valid, s, _NEG)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    if vs is not None:
+        p = p * vs
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    m_ref[...] = m_new
+
+
+def softmax_flush(o_ref, l_ref, acc_ref):
+    o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def softmax_scratch(rows: int, hd: int):
+    return [
+        pltpu.VMEM((rows, 1), jnp.float32),    # running max
+        pltpu.VMEM((rows, 1), jnp.float32),    # running denom
+        pltpu.VMEM((rows, hd), jnp.float32),   # f32 accumulator
+    ]
+
+
+SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
+
+
 def _decode_attn_kernel(
-    vl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, block_s: int, scale: float,
+    vl_ref, *refs, block_s: int, scale: float, quant: bool, group: int,
+    n_groups: int, hkv: int,
 ):
-    s_step = pl.program_id(2)
+    """Grid cell (slot, kv-head, KV chunk). int8 caches bring their
+    per-(slot, row group, kv-head) scales as flat scalar-prefetch
+    operands; each chunk expands them to one (1, block_s) row for the
+    scores and one for the probabilities."""
+    if quant:
+        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    slot, h_, s_step = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(s_step == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        softmax_init(m_ref, l_ref, acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)          # (G, hd)
-    kb = k_ref[0, :, 0, :].astype(jnp.float32)   # (block_s, hd)
-    vb = v_ref[0, :, 0, :].astype(jnp.float32)   # (block_s, hd)
     g = q.shape[0]
-    s = jax.lax.dot_general(
-        q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                    # (G, block_s)
-    col = s_step * block_s + jax.lax.broadcasted_iota(
-        jnp.int32, (g, block_s), 1
+    col = s_step * block_s + jax.lax.broadcasted_iota(jnp.int32, (g, block_s), 1)
+    valid = col < vl_ref[slot]                   # per-slot cache frontier
+    ks = vs = None
+    if quant:
+        grp = jax.lax.broadcasted_iota(jnp.int32, (1, block_s), 1) // group
+        base = (slot * n_groups + s_step * (block_s // group)) * hkv + h_
+        ks = jnp.zeros((1, block_s), jnp.float32)
+        vs = jnp.zeros((1, block_s), jnp.float32)
+        for gi in range(block_s // group):
+            ks = jnp.where(grp == gi, ks_ref[base + gi * hkv], ks)
+            vs = jnp.where(grp == gi, vs_ref[base + gi * hkv], vs)
+    softmax_step(
+        q, k_ref[0].astype(jnp.float32), v_ref[0].astype(jnp.float32), valid,
+        m_ref, l_ref, acc_ref, scale=scale, ks=ks, vs=vs,
     )
-    valid = col < vl_ref[0, 0]                   # per-slot cache frontier
-    s = jnp.where(valid, s, _NEG)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
 
     @pl.when(s_step == pl.num_programs(2) - 1)
     def _flush():
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
-
-
-def _decode_attn_q_kernel(
-    vl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, block_s: int, scale: float, group: int,
-):
-    """int8-cache variant of :func:`_decode_attn_kernel`: k/v arrive as
-    int8 codes plus per-:data:`~repro.models.layers.KV_QUANT_GROUP`-row
-    scale tiles, dequantized in VMEM right before the dot — the
-    ``quant_linear`` tile-dequant idiom applied to the cache sweep."""
-    s_step = pl.program_id(2)
-
-    @pl.when(s_step == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)          # (G, hd)
-    ks = jnp.repeat(ks_ref[0, :, 0].astype(jnp.float32)[:, None], group, axis=0)
-    vs = jnp.repeat(vs_ref[0, :, 0].astype(jnp.float32)[:, None], group, axis=0)
-    kb = k_ref[0, :, 0, :].astype(jnp.float32) * ks   # (block_s, hd)
-    vb = v_ref[0, :, 0, :].astype(jnp.float32) * vs   # (block_s, hd)
-    g = q.shape[0]
-    s = jax.lax.dot_general(
-        q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                    # (G, block_s)
-    col = s_step * block_s + jax.lax.broadcasted_iota(
-        jnp.int32, (g, block_s), 1
-    )
-    valid = col < vl_ref[0, 0]                   # per-slot cache frontier
-    s = jnp.where(valid, s, _NEG)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
-
-    @pl.when(s_step == pl.num_programs(2) - 1)
-    def _flush():
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
+        softmax_flush(o_ref, l_ref, acc_ref)
 
 
 def decode_attention_pallas(
@@ -162,13 +174,14 @@ def decode_attention_pallas(
     if h % hkv:
         raise ValueError(f"H={h} must be a multiple of Hkv={hkv}")
     g = h // hkv
-    vl = jnp.asarray(kv_valid_len, jnp.int32).reshape(-1)
-    vl = jnp.broadcast_to(vl, (b,))[:, None]     # (B, 1)
+    vl = jnp.broadcast_to(jnp.asarray(kv_valid_len, jnp.int32).reshape(-1), (b,))
     quant = k_scale is not None
     group = skv // k_scale.shape[1] if quant else 1
     if quant and group * k_scale.shape[1] != skv:
         raise ValueError(f"Smax={skv} not a whole number of scale groups")
     bs = min(block_s, skv)
+    if quant and bs % group:
+        raise ValueError(f"KV block {bs} not a multiple of scale group {group}")
     pad = (-skv) % bs
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -177,48 +190,27 @@ def decode_attention_pallas(
             gpad = (skv + pad) // group - k_scale.shape[1]
             k_scale = jnp.pad(k_scale, ((0, 0), (0, gpad), (0, 0)))
             v_scale = jnp.pad(v_scale, ((0, 0), (0, gpad), (0, 0)))
-    ns = (skv + pad) // bs
-    qg = q.reshape(b, hkv, g, hd)
-    grid = (b, hkv, ns)
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda b_, h_, s_: (b_, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, 1, g, hd), lambda b_, h_, s_: (b_, h_, 0, 0)),
-        pl.BlockSpec((1, bs, 1, hd), lambda b_, h_, s_: (b_, s_, h_, 0)),
-        pl.BlockSpec((1, bs, 1, hd), lambda b_, h_, s_: (b_, s_, h_, 0)),
-    ]
-    operands = [vl, qg, k, v]
+    prefetch = (vl,)
     if quant:
-        if bs % group:
-            raise ValueError(
-                f"KV block {bs} not a multiple of scale group {group}"
-            )
-        body = functools.partial(
-            _decode_attn_q_kernel, block_s=bs, scale=hd**-0.5, group=group
-        )
-        sc_spec = pl.BlockSpec(
-            (1, bs // group, 1), lambda b_, h_, s_: (b_, s_, h_)
-        )
-        in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
-    else:
-        body = functools.partial(_decode_attn_kernel, block_s=bs, scale=hd**-0.5)
+        prefetch += (k_scale.reshape(-1), v_scale.reshape(-1))
+    kv_spec = pl.BlockSpec((1, bs, hd), lambda b_, h_, s_, *_: (b_, s_, h_))
+    q_spec = pl.BlockSpec((1, 1, g, hd), lambda b_, h_, s_, *_: (b_, h_, 0, 0))
     out = pl.pallas_call(
-        body,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b_, h_, s_: (b_, h_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),    # running max
-            pltpu.VMEM((g, 1), jnp.float32),    # running denom
-            pltpu.VMEM((g, hd), jnp.float32),   # f32 accumulator
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        functools.partial(
+            _decode_attn_kernel, block_s=bs, scale=hd**-0.5, quant=quant,
+            group=group, n_groups=(skv + pad) // group, hkv=hkv,
         ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b, hkv, (skv + pad) // bs),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=softmax_scratch(g, hd),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
+        compiler_params=SEMANTICS,
         interpret=interpret,
-    )(*operands)
+    )(*prefetch, q.reshape(b, hkv, g, hd), lane_view(k), lane_view(v))
     return out.reshape(b, 1, h, hd)
 
 
@@ -226,94 +218,40 @@ def decode_attention_pallas(
 
 
 def _paged_decode_attn_kernel(
-    table_ref, vl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, page: int, scale: float,
+    table_ref, vl_ref, *refs, page: int, scale: float, quant: bool,
+    n_pages: int, hkv: int,
 ):
-    slot = pl.program_id(0)
-    p_step = pl.program_id(2)
+    """Grid cell (slot, kv-head, page). int8 pools bring their flat
+    per-(block, kv-head) scales beside the table: the body resolves this
+    cell's scale with the same table lookup the DMA index map used."""
+    if quant:
+        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    slot, h_, p_step = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(p_step == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        softmax_init(m_ref, l_ref, acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)          # (G, hd)
-    kb = k_ref[0, :, 0, :].astype(jnp.float32)   # (page, hd)
-    vb = v_ref[0, :, 0, :].astype(jnp.float32)   # (page, hd)
     g = q.shape[0]
-    s = jax.lax.dot_general(
-        q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                    # (G, page)
     # columns are *logical* positions: page index × page size + offset —
     # the physical block the data came from is irrelevant to masking
     col = p_step * page + jax.lax.broadcasted_iota(jnp.int32, (g, page), 1)
     valid = col < vl_ref[slot]                   # per-slot cache frontier
-    s = jnp.where(valid, s, _NEG)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    ks = vs = None
+    if quant:
+        sc = table_ref[slot * n_pages + p_step] * hkv + h_
+        ks, vs = ks_ref[sc], vs_ref[sc]
+    softmax_step(
+        q, k_ref[0].astype(jnp.float32), v_ref[0].astype(jnp.float32), valid,
+        m_ref, l_ref, acc_ref, scale=scale, ks=ks, vs=vs,
     )
-    m_ref[...] = m_new
 
     @pl.when(p_step == pl.num_programs(2) - 1)
     def _flush():
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
-
-
-def _paged_decode_attn_q_kernel(
-    table_ref, vl_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
-    o_ref, m_ref, l_ref, acc_ref, *, page: int, scale: float,
-):
-    """int8-pool variant of :func:`_paged_decode_attn_kernel`: the
-    per-(block, kv-head) scales ride next to the block table as
-    scalar-prefetch operands, so the body resolves this cell's scale with
-    the same ``table_ref[slot, page]`` lookup the DMA index map used, and
-    dequantizes the page tile in VMEM."""
-    slot = pl.program_id(0)
-    h_ = pl.program_id(1)
-    p_step = pl.program_id(2)
-
-    @pl.when(p_step == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    blk = table_ref[slot, p_step]
-    q = q_ref[0, 0].astype(jnp.float32)          # (G, hd)
-    kb = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[blk, h_]
-    vb = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[blk, h_]
-    g = q.shape[0]
-    s = jax.lax.dot_general(
-        q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                    # (G, page)
-    col = p_step * page + jax.lax.broadcasted_iota(jnp.int32, (g, page), 1)
-    valid = col < vl_ref[slot]                   # per-slot cache frontier
-    s = jnp.where(valid, s, _NEG)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
-
-    @pl.when(p_step == pl.num_programs(2) - 1)
-    def _flush():
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
+        softmax_flush(o_ref, l_ref, acc_ref)
 
 
 def paged_decode_attention_pallas(
@@ -335,12 +273,12 @@ def paged_decode_attention_pallas(
     reservation keeps ``kv_valid_len`` within allocated pages);
     kv_valid_len scalar or (B,). Returns (B, 1, H, hd).
 
-    Grid (slot, kv-head, page): the block table is a scalar-prefetch
-    operand, so the k/v index maps resolve the *physical* block for each
-    (slot, page) cell ahead of the DMA — the pool is never gathered into
-    a contiguous per-slot cache. With ``k_scale``/``v_scale`` (N, Hkv)
-    the pools are int8: the scales prefetch alongside the table and each
-    page tile dequantizes in VMEM (DESIGN §15).
+    Grid (slot, kv-head, page): the block table (flattened) is a
+    scalar-prefetch operand, so the k/v index maps resolve the *physical*
+    block for each (slot, page) cell ahead of the DMA — the pool is never
+    gathered into a contiguous per-slot cache. With ``k_scale``/``v_scale``
+    (N, Hkv) the pools are int8: the scales prefetch alongside the table
+    and each page tile dequantizes in VMEM (DESIGN §15).
     """
     b, sq, h, hd = q.shape
     if sq != 1:
@@ -352,57 +290,36 @@ def paged_decode_attention_pallas(
         raise ValueError(f"table rows {table.shape[0]} != batch {b}")
     g = h // hkv
     n_pages = table.shape[1]
-    vl = jnp.asarray(kv_valid_len, jnp.int32).reshape(-1)
-    vl = jnp.broadcast_to(vl, (b,))
+    vl = jnp.broadcast_to(jnp.asarray(kv_valid_len, jnp.int32).reshape(-1), (b,))
     # clamp the sentinel in the wrapper: index maps must name a resident
     # block, and clamped pages lie past the frontier anyway
-    tbl = jnp.minimum(table.astype(jnp.int32), n - 1)
-    qg = q.reshape(b, hkv, g, hd)
-    grid = (b, hkv, n_pages)
+    tbl = jnp.minimum(table.astype(jnp.int32), n - 1).reshape(-1)
     quant = k_scale is not None
-    n_prefetch = 4 if quant else 2
+    prefetch = (tbl, vl)
+    if quant:
+        prefetch += (k_scale.reshape(-1), v_scale.reshape(-1))
 
     def kv_map(b_, h_, p_, table_ref, *_):
-        return (table_ref[b_, p_], 0, h_, 0)
+        return (table_ref[b_ * n_pages + p_], 0, h_)
 
-    def q_map(b_, h_, p_, *_):
-        return (b_, h_, 0, 0)
-
-    kv_spec = pl.BlockSpec((1, page, 1, hd), kv_map)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, hd), q_map),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),    # running max
-            pltpu.VMEM((g, 1), jnp.float32),    # running denom
-            pltpu.VMEM((g, hd), jnp.float32),   # f32 accumulator
-        ],
-    )
-    if quant:
-        body = functools.partial(
-            _paged_decode_attn_q_kernel, page=page, scale=hd**-0.5
-        )
-        operands = (tbl, vl, k_scale, v_scale, qg, k_pool, v_pool)
-    else:
-        body = functools.partial(
-            _paged_decode_attn_kernel, page=page, scale=hd**-0.5
-        )
-        operands = (tbl, vl, qg, k_pool, v_pool)
+    q_spec = pl.BlockSpec((1, 1, g, hd), lambda b_, h_, p_, *_: (b_, h_, 0, 0))
+    kv_spec = pl.BlockSpec((1, page, hd), kv_map)
     out = pl.pallas_call(
-        body,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        functools.partial(
+            _paged_decode_attn_kernel, page=page, scale=hd**-0.5, quant=quant,
+            n_pages=n_pages, hkv=hkv,
         ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b, hkv, n_pages),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=softmax_scratch(g, hd),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
+        compiler_params=SEMANTICS,
         interpret=interpret,
-    )(*operands)
+    )(*prefetch, q.reshape(b, hkv, g, hd), lane_view(k_pool), lane_view(v_pool))
     return out.reshape(b, 1, h, hd)
 
 

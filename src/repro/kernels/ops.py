@@ -1,13 +1,14 @@
 """jit'd public wrappers around the Pallas kernels, with backend dispatch.
 
-Backends (``REPRO_KERNEL_BACKEND`` env var or :func:`set_backend`):
+The backend follows the platform (:func:`get_backend`):
 
-* ``jnp``              — pure-jnp oracle path (default; XLA fuses it. The
-                          only executable path on this CPU container for
-                          real workloads).
-* ``pallas``           — Mosaic-compiled kernels (TPU target).
-* ``pallas_interpret`` — kernel bodies interpreted in Python (CPU
-                          validation; used by the test sweeps).
+* ``pallas``           — Mosaic-compiled kernels, whenever JAX's default
+                          backend is a TPU.
+* ``jnp``              — the pure-jnp oracle path everywhere else (XLA
+                          fuses it).
+* ``pallas_interpret`` — kernel bodies interpreted in Python; only inside
+                          :func:`use_backend`, which the test sweeps use to
+                          pin kernel semantics on the CPU.
 
 All wrappers accept arbitrary leading batch dims and handle tile padding.
 The Pallas paths carry a custom VJP that reproduces the paper's sparse
@@ -18,7 +19,6 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -47,31 +47,35 @@ from repro.kernels.topk_select import topk_select_pallas
 from repro.quant.qtensor import QuantizedTensor, dequantize
 
 _BACKENDS = ("jnp", "pallas", "pallas_interpret")
-_backend = os.environ.get("REPRO_KERNEL_BACKEND", "jnp")
-
-
-def set_backend(name: str) -> None:
-    global _backend
-    if name not in _BACKENDS:
-        raise ValueError(f"backend {name!r} not in {_BACKENDS}")
-    _backend = name
+_override: str | None = None
 
 
 def get_backend() -> str:
-    return _backend
+    """``pallas`` on a TPU, ``jnp`` elsewhere, unless :func:`use_backend`
+    scopes another. Read at trace time: a jitted function keeps the
+    backend it was traced under."""
+    if _override is not None:
+        return _override
+    return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
 @contextlib.contextmanager
 def use_backend(name: str):
-    """Scoped :func:`set_backend` — restores the previous backend even when
-    the body raises, so a failing test sweep can't leak the Pallas backend
-    into later tests."""
-    prev = get_backend()
-    set_backend(name)
+    """Scope a backend — restores the previous one even when the body
+    raises, so a failing test sweep can't leak the Pallas backend into
+    later tests."""
+    global _override
+    if name not in _BACKENDS:
+        raise ValueError(f"backend {name!r} not in {_BACKENDS}")
+    prev, _override = _override, name
     try:
         yield
     finally:
-        set_backend(prev)
+        _override = prev
+
+
+def _interpret() -> bool:
+    return get_backend() == "pallas_interpret"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> tuple[jax.Array, int]:
@@ -87,7 +91,7 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> tuple[jax.Array, int]:
 # ---------------------------------------------------------------- delta apply
 
 
-@jax.custom_vjp
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _delta_apply_pallas(x2d, idx, val, interpret):
     bm = 128 if x2d.shape[0] >= 128 else 8
     xp, m = _pad_to(x2d, 0, bm)
@@ -98,21 +102,25 @@ def _delta_apply_pallas(x2d, idx, val, interpret):
 
 
 def _delta_fwd(x2d, idx, val, interpret):
-    return _delta_apply_pallas(x2d, idx, val, interpret), (x2d, idx, val, interpret)
+    return _delta_apply_pallas(x2d, idx, val, interpret), (x2d, idx, val)
 
 
-def _delta_bwd(res, dy):
-    x2d, idx, val, interpret = res
+def _dval(x2d, idx, dy, interpret):
+    """(k, d_out) f32 gradient of the bypass values, via the dval kernel."""
     bm = 128 if x2d.shape[0] >= 128 else 8
     xp, _ = _pad_to(x2d, 0, bm)
     dyp, _ = _pad_to(dy, 0, bm)
     ip, n = _pad_to(idx, 1, 128)
-    dyp2, _ = _pad_to(dyp, 1, 128)
-    dval = sparse_delta_dval_pallas(xp, ip, dyp2, block_m=bm, interpret=interpret)
-    dval = dval[:, :n].astype(val.dtype)
+    dyp, _ = _pad_to(dyp, 1, 128)
+    return sparse_delta_dval_pallas(xp, ip, dyp, block_m=bm, interpret=interpret)[:, :n]
+
+
+def _delta_bwd(interpret, res, dy):
+    x2d, idx, val = res
+    dval = _dval(x2d, idx, dy, interpret).astype(val.dtype)
     dx = ref.sparse_delta_dx_ref(idx, val, dy, x2d.shape[1]).astype(x2d.dtype)
     didx = np.zeros(idx.shape, dtype=jax.dtypes.float0)
-    return dx, didx, dval, None
+    return dx, didx, dval
 
 
 _delta_apply_pallas.defvjp(_delta_fwd, _delta_bwd)
@@ -120,12 +128,12 @@ _delta_apply_pallas.defvjp(_delta_fwd, _delta_bwd)
 
 def delta_apply(x: jax.Array, idx: jax.Array, val: jax.Array) -> jax.Array:
     """x (..., d_in) × Delta (k, d_out) -> (..., d_out)."""
-    if _backend == "jnp":
+    if get_backend() == "jnp":
         xg = x[..., idx]
         return jnp.sum(xg * val.astype(x.dtype), axis=-2)
     lead = x.shape[:-1]
     x2d = x.reshape(-1, x.shape[-1])
-    y = _delta_apply_pallas(x2d, idx, val, _backend == "pallas_interpret")
+    y = _delta_apply_pallas(x2d, idx, val, _interpret())
     return y.reshape(*lead, idx.shape[-1])
 
 
@@ -139,12 +147,17 @@ def delta_apply_batched(
     the serving engine passes (B,) ids against (B, S, d_in) activations.
     Inference-only on the Pallas backends (no custom VJP; training uses the
     single-tenant paths).
+
+    Under a TP serve mesh the stacks split with their host matrix — on
+    d_out for column-parallel linears, while row-parallel ones see ``x``
+    split on d_in — and a Mosaic kernel cannot be partitioned
+    automatically, so the gather form runs there and GSPMD partitions it.
     """
     lead = x.shape[:-1]
     if aid.ndim < len(lead):
         aid = aid.reshape(aid.shape + (1,) * (len(lead) - aid.ndim))
     aid = jnp.broadcast_to(aid, lead).astype(jnp.int32)
-    if _backend == "jnp":
+    if get_backend() == "jnp" or tp_ctx.serve_tp() > 1:
         idx_m = jnp.take(idx, aid, axis=0)  # (..., k, d_out)
         val_m = jnp.take(val, aid, axis=0)
         xg = jnp.take_along_axis(x[..., None, :], idx_m, axis=-1)
@@ -157,7 +170,7 @@ def delta_apply_batched(
     ip, n = _pad_to(idx, 2, 128)
     vp, _ = _pad_to(val, 2, 128)
     y = sparse_delta_batched_pallas(
-        xp, ip, vp, ap, block_m=bm, interpret=_backend == "pallas_interpret"
+        xp, ip, vp, ap, block_m=bm, interpret=_interpret()
     )
     return y[:m, :n].reshape(*lead, idx.shape[-1])
 
@@ -165,7 +178,7 @@ def delta_apply_batched(
 # --------------------------------------------------------------- fused linear
 
 
-@jax.custom_vjp
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _fused_linear_pallas(x2d, w, idx, val, bias, interpret, w_frozen):
     bm = 128 if x2d.shape[0] >= 128 else 8
     xp, m = _pad_to(x2d, 0, bm)
@@ -175,11 +188,11 @@ def _fused_linear_pallas(x2d, w, idx, val, bias, interpret, w_frozen):
 
 def _fused_fwd(x2d, w, idx, val, bias, interpret, w_frozen):
     y = _fused_linear_pallas(x2d, w, idx, val, bias, interpret, w_frozen)
-    return y, (x2d, w, idx, val, bias, interpret, w_frozen)
+    return y, (x2d, w, idx, val, bias)
 
 
-def _fused_bwd(res, dy):
-    x2d, w, idx, val, bias, interpret, w_frozen = res
+def _fused_bwd(interpret, w_frozen, res, dy):
+    x2d, w, idx, val, bias = res
     # dx: dense transpose + sparse scatter.
     dx = jnp.dot(dy, w.T) + ref.sparse_delta_dx_ref(idx, val, dy, x2d.shape[1]).astype(x2d.dtype)
     if w_frozen:
@@ -188,17 +201,10 @@ def _fused_bwd(res, dy):
         dw = jnp.zeros(w.shape, w.dtype)
     else:
         dw = jnp.dot(x2d.T, dy).astype(w.dtype)
-    bm = 128 if x2d.shape[0] >= 128 else 8
-    xp, _ = _pad_to(x2d, 0, bm)
-    dyp, _ = _pad_to(dy, 0, bm)
-    ip, n = _pad_to(idx, 1, 128)
-    dyp2, _ = _pad_to(dyp, 1, 128)
-    dval = sparse_delta_dval_pallas(xp, ip, dyp2, block_m=bm, interpret=interpret)[
-        :, :n
-    ].astype(val.dtype)
+    dval = _dval(x2d, idx, dy, interpret).astype(val.dtype)
     dbias = None if bias is None else jnp.sum(dy, axis=0).astype(bias.dtype)
     didx = np.zeros(idx.shape, dtype=jax.dtypes.float0)
-    return dx, dw, didx, dval, dbias, None, None
+    return dx, dw, didx, dval, dbias
 
 
 _fused_linear_pallas.defvjp(_fused_fwd, _fused_bwd)
@@ -219,7 +225,7 @@ def fused_linear(
     backward statically skips the dense ``dw`` matmul and returns zeros for
     it. Callers that differentiate W must leave it False.
     """
-    if _backend == "jnp":
+    if get_backend() == "jnp":
         # enforce the frozen contract uniformly across backends: the
         # Pallas bwd returns zero dw, so the jnp path must too
         y = jnp.dot(x, jax.lax.stop_gradient(w) if w_frozen else w)
@@ -230,7 +236,7 @@ def fused_linear(
     lead = x.shape[:-1]
     x2d = x.reshape(-1, x.shape[-1])
     y = _fused_linear_pallas(
-        x2d, w, idx, val, bias, _backend == "pallas_interpret", w_frozen
+        x2d, w, idx, val, bias, _interpret(), w_frozen
     )
     return y.reshape(*lead, w.shape[-1])
 
@@ -242,7 +248,7 @@ def _q_meta(qw: QuantizedTensor):
     # interpret rides in the static meta: a traced bool would break
     # pallas_call(interpret=...) when the wrapper runs under jit (the
     # serving megastep jits the whole decode chunk).
-    return (qw.qdtype, qw.block, _backend == "pallas_interpret")
+    return (qw.qdtype, qw.block, _interpret())
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -250,10 +256,9 @@ def _fused_linear_q(meta, x2d, data, scales, idx, val, bias):
     qdtype, block, interpret = meta
     bm = 128 if x2d.shape[0] >= 128 else 8
     xp, m = _pad_to(x2d, 0, bm)
-    bk = min(512, x2d.shape[1])
     y = fused_linear_q_pallas(
         xp, data, scales, idx, val, bias,
-        qdtype=qdtype, block=block, block_m=bm, block_k=bk, interpret=interpret,
+        qdtype=qdtype, block=block, block_m=bm, interpret=interpret,
     )
     return y[:m]
 
@@ -274,14 +279,7 @@ def _fused_q_bwd(meta, res, dy):
     dx = jnp.dot(dy, w.T).astype(x2d.dtype) + ref.sparse_delta_dx_ref(
         idx, val, dy, x2d.shape[1]
     ).astype(x2d.dtype)
-    bm = 128 if x2d.shape[0] >= 128 else 8
-    xp, _ = _pad_to(x2d, 0, bm)
-    dyp, _ = _pad_to(dy, 0, bm)
-    ip, n = _pad_to(idx, 1, 128)
-    dyp2, _ = _pad_to(dyp, 1, 128)
-    dval = sparse_delta_dval_pallas(xp, ip, dyp2, block_m=bm, interpret=interpret)[
-        :, :n
-    ].astype(val.dtype)
+    dval = _dval(x2d, idx, dy, interpret).astype(val.dtype)
     dbias = None if bias is None else jnp.sum(dy, axis=0).astype(bias.dtype)
     ddata = np.zeros(data.shape, dtype=jax.dtypes.float0)
     didx = np.zeros(idx.shape, dtype=jax.dtypes.float0)
@@ -307,7 +305,7 @@ def fused_linear_q(
     only ``dx``/``dval`` — training on a quantized base never materialises
     a dense weight gradient.
     """
-    if _backend == "jnp":
+    if get_backend() == "jnp":
         y = jnp.dot(x, dequantize(qw).astype(x.dtype))
         y = y + delta_apply(x, idx, val)
         if bias is not None:
@@ -336,7 +334,7 @@ def matmul_q(x: jax.Array, w, *, tp_col_sharded: bool = False) -> jax.Array:
     """
     if not isinstance(w, QuantizedTensor):
         return jnp.dot(x, w)
-    if _backend == "jnp":
+    if get_backend() == "jnp":
         return jnp.dot(x, dequantize(w).astype(x.dtype))
     lead = x.shape[:-1]
     x2d = x.reshape(-1, x.shape[-1])
@@ -346,7 +344,7 @@ def matmul_q(x: jax.Array, w, *, tp_col_sharded: bool = False) -> jax.Array:
         tp = tp_ctx.serve_tp()
         if mesh is not None and tp > 1 and n % tp == 0:
             y = matmul_q_cols_sharded(
-                x2d, w, mesh, interpret=_backend == "pallas_interpret"
+                x2d, w, mesh, interpret=_interpret()
             )
             return y.reshape(*lead, n)
     # a zero bypass rides the fused kernel through the custom-VJP wrapper,
@@ -370,7 +368,7 @@ def _serve_mesh_for_kv(num_kv_heads: int):
     callers — they get the replicated kernel, still correct)."""
     mesh = tp_ctx.serve_mesh()
     tp = tp_ctx.serve_tp()
-    if mesh is None or tp <= 1 or _backend == "jnp":
+    if mesh is None or tp <= 1 or get_backend() == "jnp":
         return None
     if num_kv_heads % tp:
         return None
@@ -391,7 +389,7 @@ def decode_attention(
     Dispatch policy — *when* this replaces the dense masked softmax —
     lives in ``models.attention.attention``.
     """
-    if _backend == "jnp":
+    if get_backend() == "jnp":
         if k_scale is not None:
             return ref.decode_attention_q_ref(
                 q, k, v, k_scale, v_scale, kv_valid_len
@@ -402,11 +400,11 @@ def decode_attention(
         return decode_attention_sharded(
             q, k, v, kv_valid_len, mesh,
             k_scale=k_scale, v_scale=v_scale,
-            interpret=_backend == "pallas_interpret",
+            interpret=_interpret(),
         )
     return decode_attention_pallas(
         q, k, v, kv_valid_len, k_scale=k_scale, v_scale=v_scale,
-        interpret=_backend == "pallas_interpret",
+        interpret=_interpret(),
     )
 
 
@@ -424,7 +422,7 @@ def paged_decode_attention(
     gather ever materialises). With ``k_scale``/``v_scale`` (N, Hkv) the
     pool is int8 and the scales prefetch beside the table (DESIGN §15).
     """
-    if _backend == "jnp":
+    if get_backend() == "jnp":
         if k_scale is not None:
             return ref.paged_decode_attention_q_ref(
                 q, k_pool, v_pool, k_scale, v_scale, table, kv_valid_len
@@ -435,12 +433,12 @@ def paged_decode_attention(
         return paged_decode_attention_sharded(
             q, k_pool, v_pool, table, kv_valid_len, mesh,
             k_scale=k_scale, v_scale=v_scale,
-            interpret=_backend == "pallas_interpret",
+            interpret=_interpret(),
         )
     return paged_decode_attention_pallas(
         q, k_pool, v_pool, table, kv_valid_len,
         k_scale=k_scale, v_scale=v_scale,
-        interpret=_backend == "pallas_interpret",
+        interpret=_interpret(),
     )
 
 
@@ -459,7 +457,7 @@ def prefill_attention(
     straight from the pool, online softmax in VMEM). With ``k_scale``/
     ``v_scale`` (N, Hkv) the pool is int8, dequantized per page tile.
     """
-    if _backend == "jnp":
+    if get_backend() == "jnp":
         if k_scale is not None:
             return ref.paged_prefill_attention_q_ref(
                 q, k_pool, v_pool, k_scale, v_scale, table,
@@ -473,12 +471,12 @@ def prefill_attention(
         return paged_prefill_attention_sharded(
             q, k_pool, v_pool, table, q_offset, kv_valid_len, mesh,
             k_scale=k_scale, v_scale=v_scale,
-            interpret=_backend == "pallas_interpret",
+            interpret=_interpret(),
         )
     return paged_prefill_attention_pallas(
         q, k_pool, v_pool, table, q_offset, kv_valid_len,
         k_scale=k_scale, v_scale=v_scale,
-        interpret=_backend == "pallas_interpret",
+        interpret=_interpret(),
     )
 
 
@@ -487,6 +485,6 @@ def prefill_attention(
 
 def topk_select(w: jax.Array, k: int) -> jax.Array:
     """Offline Phase-1 selection; (d_in, d_out) -> (k, d_out) int32."""
-    if _backend == "jnp":
+    if get_backend() == "jnp":
         return ref.topk_select_ref(w, k)
-    return topk_select_pallas(w, k, interpret=_backend == "pallas_interpret")
+    return topk_select_pallas(w, k, interpret=_interpret())

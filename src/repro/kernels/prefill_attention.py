@@ -47,31 +47,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
-_NEG = -1e30
-
+from repro.kernels.decode_attention import (
+    SEMANTICS,
+    lane_view,
+    softmax_flush,
+    softmax_init,
+    softmax_scratch,
+    softmax_step,
+)
 
 def _paged_prefill_attn_kernel(
-    table_ref, qoff_ref, vl_ref, q_ref, k_ref, v_ref, o_ref,
-    m_ref, l_ref, acc_ref, *, page: int, g: int, scale: float,
+    table_ref, qoff_ref, vl_ref, *refs, page: int, g: int, scale: float,
+    quant: bool, n_pages: int, hkv: int,
 ):
-    slot = pl.program_id(0)
-    p_step = pl.program_id(2)
+    """Grid cell (slot, kv-head, page); int8 pools bring their flat
+    per-(block, kv-head) scales beside the table (DESIGN §15)."""
+    if quant:
+        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    slot, h_, p_step = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(p_step == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        softmax_init(m_ref, l_ref, acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)          # (C·G, hd)
-    kb = k_ref[0, :, 0, :].astype(jnp.float32)   # (page, hd)
-    vb = v_ref[0, :, 0, :].astype(jnp.float32)   # (page, hd)
     cg = q.shape[0]
-    s = jax.lax.dot_general(
-        q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                    # (C·G, page)
     # columns are *logical* positions; rows fold (query, group): row r is
     # query r // g, so its causal frontier is q_offset + r // g
     col = p_step * page + jax.lax.broadcasted_iota(jnp.int32, (cg, page), 1)
@@ -79,72 +81,18 @@ def _paged_prefill_attn_kernel(
         jnp.int32, (cg, page), 0
     ) // g
     valid = (col <= qpos) & (col < vl_ref[slot])
-    s = jnp.where(valid, s, _NEG)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    ks = vs = None
+    if quant:
+        sc = table_ref[slot * n_pages + p_step] * hkv + h_
+        ks, vs = ks_ref[sc], vs_ref[sc]
+    softmax_step(
+        q, k_ref[0].astype(jnp.float32), v_ref[0].astype(jnp.float32), valid,
+        m_ref, l_ref, acc_ref, scale=scale, ks=ks, vs=vs,
     )
-    m_ref[...] = m_new
 
     @pl.when(p_step == pl.num_programs(2) - 1)
     def _flush():
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
-
-
-def _paged_prefill_attn_q_kernel(
-    table_ref, qoff_ref, vl_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref,
-    m_ref, l_ref, acc_ref, *, page: int, g: int, scale: float,
-):
-    """int8-pool variant of :func:`_paged_prefill_attn_kernel`: the
-    per-(block, kv-head) scales prefetch beside the block table and each
-    KV page dequantizes in VMEM before the score dot (DESIGN §15)."""
-    slot = pl.program_id(0)
-    h_ = pl.program_id(1)
-    p_step = pl.program_id(2)
-
-    @pl.when(p_step == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    blk = table_ref[slot, p_step]
-    q = q_ref[0, 0].astype(jnp.float32)          # (C·G, hd)
-    kb = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[blk, h_]
-    vb = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[blk, h_]
-    cg = q.shape[0]
-    s = jax.lax.dot_general(
-        q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                    # (C·G, page)
-    col = p_step * page + jax.lax.broadcasted_iota(jnp.int32, (cg, page), 1)
-    qpos = qoff_ref[slot] + jax.lax.broadcasted_iota(
-        jnp.int32, (cg, page), 0
-    ) // g
-    valid = (col <= qpos) & (col < vl_ref[slot])
-    s = jnp.where(valid, s, _NEG)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
-
-    @pl.when(p_step == pl.num_programs(2) - 1)
-    def _flush():
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
+        softmax_flush(o_ref, l_ref, acc_ref)
 
 
 def paged_prefill_attention_pallas(
@@ -177,61 +125,38 @@ def paged_prefill_attention_pallas(
         raise ValueError(f"table rows {table.shape[0]} != batch {b}")
     g = h // hkv
     n_pages = table.shape[1]
-    qoff = jnp.broadcast_to(
-        jnp.asarray(q_offset, jnp.int32).reshape(-1), (b,)
-    )
-    vl = jnp.broadcast_to(
-        jnp.asarray(kv_valid_len, jnp.int32).reshape(-1), (b,)
-    )
-    tbl = jnp.minimum(table.astype(jnp.int32), n - 1)
+    qoff = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32).reshape(-1), (b,))
+    vl = jnp.broadcast_to(jnp.asarray(kv_valid_len, jnp.int32).reshape(-1), (b,))
+    tbl = jnp.minimum(table.astype(jnp.int32), n - 1).reshape(-1)
     # fold (query, group) into one row axis: (B, Hkv, C·G, hd)
     qg = q.reshape(b, c, hkv, g, hd).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(b, hkv, c * g, hd)
-    grid = (b, hkv, n_pages)
     quant = k_scale is not None
-    n_prefetch = 5 if quant else 3
+    prefetch = (tbl, qoff, vl)
+    if quant:
+        prefetch += (k_scale.reshape(-1), v_scale.reshape(-1))
 
     def kv_map(b_, h_, p_, table_ref, *_):
-        return (table_ref[b_, p_], 0, h_, 0)
+        return (table_ref[b_ * n_pages + p_], 0, h_)
 
-    def q_map(b_, h_, p_, *_):
-        return (b_, h_, 0, 0)
-
-    kv_spec = pl.BlockSpec((1, page, 1, hd), kv_map)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, c * g, hd), q_map),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, c * g, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((c * g, 1), jnp.float32),    # running max
-            pltpu.VMEM((c * g, 1), jnp.float32),    # running denom
-            pltpu.VMEM((c * g, hd), jnp.float32),   # f32 accumulator
-        ],
-    )
-    if quant:
-        body = functools.partial(
-            _paged_prefill_attn_q_kernel, page=page, g=g, scale=hd**-0.5
-        )
-        operands = (tbl, qoff, vl, k_scale, v_scale, qg, k_pool, v_pool)
-    else:
-        body = functools.partial(
-            _paged_prefill_attn_kernel, page=page, g=g, scale=hd**-0.5
-        )
-        operands = (tbl, qoff, vl, qg, k_pool, v_pool)
+    q_spec = pl.BlockSpec((1, 1, c * g, hd), lambda b_, h_, p_, *_: (b_, h_, 0, 0))
+    kv_spec = pl.BlockSpec((1, page, hd), kv_map)
     out = pl.pallas_call(
-        body,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, c * g, hd), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        functools.partial(
+            _paged_prefill_attn_kernel, page=page, g=g, scale=hd**-0.5,
+            quant=quant, n_pages=n_pages, hkv=hkv,
         ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b, hkv, n_pages),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=softmax_scratch(c * g, hd),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, c * g, hd), q.dtype),
+        compiler_params=SEMANTICS,
         interpret=interpret,
-    )(*operands)
+    )(*prefetch, qg, lane_view(k_pool), lane_view(v_pool))
     out = out.reshape(b, hkv, c, g, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(b, c, h, hd)
 

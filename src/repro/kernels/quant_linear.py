@@ -4,18 +4,25 @@
 each K-tile of the packed base weight is dequantized *in VMEM* — int8 codes
 (or NF4 nibbles) × per-block scales — immediately before it feeds the MXU,
 so the dense fp weight never exists in HBM. The bypass entries whose source
-index falls inside the K-tile ride the same accumulator (masked lane
-gather), exactly like ``fused_linear.py``; the output tile is written once.
+index falls inside the K-tile ride the same accumulator (a second MXU
+product against the tile's densified delta), exactly like
+``fused_linear.py``; the output tile is written once.
 
 HBM traffic per (bm, bn) output tile drops from ``bk·bn·4`` bytes of fp32
 weight to ``bk·bn`` (int8) or ``bk·bn/2 + scales`` (NF4) per K step — the
 whole point of serving N tenants off one quantized base.
 
 Grid: (M/bm parallel, N/bn parallel, K/bk sequential-accumulate). ``block``
-(scale granularity) must divide ``bk`` so each K-tile owns whole scale rows.
+(scale granularity) must divide ``bk`` so each K-tile owns whole scale rows;
+the scales ride as a (K/bk, bk/block, N) view so their block spans its full
+middle axis.
 
-NF4 codebook lookup inside the kernel is a 16-way select-accumulate over
-static code constants (VPU-friendly; no gather needed for a 16-entry table).
+NF4 packs rows 2r and 2r+1 of a tile into the low and high nibble of one
+byte. Re-interleaving them in VMEM is a sublane shuffle Mosaic does not
+lower, so the wrapper splits ``x`` into its even and odd columns instead,
+and each K step runs two half-height products:
+``x_even @ W_lo + x_odd @ W_hi`` (and the bypass likewise). The codebook
+lookup is a 16-way select over static code constants on the VPU.
 """
 
 from __future__ import annotations
@@ -27,53 +34,51 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+from repro.kernels.sparse_delta import delta_tile, pick_block
 from repro.quant.qtensor import NF4_CODES
 
 
-def _dequant_tile(data, scales, *, bk: int, block: int, qdtype: str) -> jax.Array:
-    """Packed (bk[, /2], bn) tile + (bk/block, bn) scales -> f32 (bk, bn)."""
-    if qdtype == "nf4":
-        lo = (data & 0xF).astype(jnp.int32)
-        hi = ((data >> 4) & 0xF).astype(jnp.int32)
-        codes = jnp.stack([lo, hi], axis=1).reshape(bk, data.shape[-1])
-        wt = jnp.zeros(codes.shape, jnp.float32)
-        for c, v in enumerate(NF4_CODES):  # 16 static selects on the VPU
-            wt = jnp.where(codes == c, jnp.float32(v), wt)
+def _nf4_values(codes: jax.Array) -> jax.Array:
+    wt = jnp.zeros(codes.shape, jnp.float32)
+    for c, v in enumerate(NF4_CODES):  # 16 static selects on the VPU
+        wt = jnp.where(codes == c, jnp.float32(v), wt)
+    return wt
+
+
+def _fused_q_kernel(*refs, bk: int, block: int, qdtype: str, has_bias: bool):
+    nf4 = qdtype == "nf4"
+    if nf4:
+        xe_ref, xo_ref, data_ref, scales_ref, idx_ref, val_ref, b_ref, y_ref, acc_ref = refs
     else:
-        wt = data.astype(jnp.float32)
-    s = jnp.repeat(scales.astype(jnp.float32), block, axis=0)  # (bk, bn)
-    return wt * s
-
-
-def _fused_q_kernel(
-    x_ref, data_ref, scales_ref, idx_ref, val_ref, b_ref, y_ref, acc_ref,
-    *, k: int, bk: int, block: int, qdtype: str, has_bias: bool,
-):
+        x_ref, data_ref, scales_ref, idx_ref, val_ref, b_ref, y_ref, acc_ref = refs
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]  # (bm, bk)
-    wt = _dequant_tile(
-        data_ref[...], scales_ref[...], bk=bk, block=block, qdtype=qdtype
-    )
-    acc_ref[...] += jnp.dot(
-        x.astype(jnp.float32), wt, preferred_element_type=jnp.float32
-    )
-
-    # Bypass entries landing in this K tile (same scheme as fused_linear).
-    local = idx_ref[...] - kk * bk  # (k, bn)
-    val = val_ref[...]
-    in_tile = (local >= 0) & (local < bk)
-    for j in range(k):
-        safe = jnp.clip(local[j], 0, bk - 1)
-        xg = jnp.take(x, safe, axis=1).astype(jnp.float32)  # (bm, bn)
-        acc_ref[...] += jnp.where(
-            in_tile[j][None, :], xg * val[j].astype(jnp.float32), 0.0
+    sc = scales_ref[0].astype(jnp.float32)  # (bk / block, bn)
+    idx, val = idx_ref[...], val_ref[...]
+    if nf4:
+        data = data_ref[...].astype(jnp.int32)  # (bk/2, bn), two codes a byte
+        s = jnp.repeat(sc, block // 2, axis=0)
+        parts = (
+            (xe_ref[...], data & 0xF, 0),
+            (xo_ref[...], (data >> 4) & 0xF, 1),
         )
+        parts = [(x, _nf4_values(c) * s, par) for x, c, par in parts]
+        stride, rows = 2, bk // 2
+    else:
+        parts = [(x_ref[...], data_ref[...].astype(jnp.float32)
+                  * jnp.repeat(sc, block, axis=0), 0)]
+        stride, rows = 1, bk
+    acc = acc_ref[...]
+    for x, wt, par in parts:
+        d = delta_tile(idx, val, kk * bk + par, rows, x.dtype, stride)
+        acc += jnp.dot(
+            x.astype(jnp.float32), wt, preferred_element_type=jnp.float32
+        ) + jnp.dot(x, d, preferred_element_type=jnp.float32)
+    acc_ref[...] = acc
 
     @pl.when(kk == pl.num_programs(2) - 1)
     def _flush():
@@ -106,37 +111,38 @@ def fused_linear_q_pallas(
     m, kdim = x.shape
     n = data.shape[-1]
     k = idx.shape[0]
-    bm, bn, bk = min(block_m, m), min(block_n, n), min(block_k, kdim)
+    bm, bn, bk = min(block_m, m), min(block_n, n), pick_block(kdim, block_k)
     if bk % block:
         raise ValueError(f"K tile {bk} must be a multiple of scale block {block}")
     if m % bm or n % bn or kdim % bk:
         raise ValueError(f"shapes {(m, kdim, n)} must tile by {(bm, bk, bn)}")
-    packed_rows = bk // 2 if qdtype == "nf4" else bk
-    grid = (m // bm, n // bn, kdim // bk)
+    nf4 = qdtype == "nf4"
+    packed_rows = bk // 2 if nf4 else bk
     has_bias = bias is not None
-    b = bias if has_bias else jnp.zeros((n,), x.dtype)
+    b = (bias if has_bias else jnp.zeros((n,), x.dtype)).reshape(1, n)
+    xs = (x[:, 0::2], x[:, 1::2]) if nf4 else (x,)
+    x_spec = pl.BlockSpec((bm, packed_rows), lambda i, j, kk: (i, kk))
     return pl.pallas_call(
         functools.partial(
-            _fused_q_kernel, k=k, bk=bk, block=block, qdtype=qdtype,
+            _fused_q_kernel, bk=bk, block=block, qdtype=qdtype,
             has_bias=has_bias,
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+        grid=(m // bm, n // bn, kdim // bk),
+        in_specs=[x_spec] * len(xs) + [
             pl.BlockSpec((packed_rows, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bk // block, bn), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((1, bk // block, bn), lambda i, j, kk: (kk, 0, j)),
             pl.BlockSpec((k, bn), lambda i, j, kk: (0, j)),
             pl.BlockSpec((k, bn), lambda i, j, kk: (0, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(x, data, scales, idx, val, b)
+    )(*xs, data, scales.reshape(kdim // bk, bk // block, n), idx, val, b)
 
 
 # --------------------------------------------------- TP-sharded dispatch

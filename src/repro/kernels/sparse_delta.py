@@ -1,16 +1,18 @@
-"""Pallas TPU kernel for the NeuroAda bypass apply (paper Eq. 4, footnote 2).
+"""Pallas TPU kernels for the NeuroAda bypass apply (paper Eq. 4, footnote 2).
 
-Computes ``yΔ[m, o] = Σ_j val[j, o] · x[m, idx[j, o]]`` without materialising
-the ``(M, k, d_out)`` gathered tensor the pure-jnp path creates: each grid
-cell holds one ``(bm, d_in)`` slab of activations in VMEM and produces one
-``(bm, bn)`` output tile, looping the (small, static) k bypasses with a
-lane-dimension gather. This is the TPU-native analogue of the paper's
-"fused scatter-add" CUDA path — gathers along lanes instead of scatters,
-because the gather transpose is what backward needs anyway.
+Computes ``yΔ[m, o] = Σ_j val[j, o] · x[m, idx[j, o]]`` without
+materialising the ``(M, k, d_out)`` gathered tensor the pure-jnp path
+creates, and without a lane gather, which Mosaic cannot lower at these
+widths. Each grid cell instead densifies the delta for one ``(bk, bn)``
+source-row × output-column tile in VMEM — ``S[i, o] = Σ_j val[j, o] ·
+[idx[j, o] == i]``, k compares per element on the VPU — and contracts the
+``(bm, bk)`` activation tile with it on the MXU. The top-k indices of a
+column are distinct, so ``S`` holds each value exactly and the product is
+the gather, accumulated in f32. The dense delta exists one tile at a time
+in VMEM, never in HBM: the paper's memory claim holds, while the MXU does
+a dense matmul's worth of work for the bypass.
 
-VMEM budget per cell: bm·d_in·2B (x slab) + k·bn·(4+2)B + bm·bn·4B.
-With bm=128, d_in=53 248 (largest assigned arch), bf16: ≈13.6 MB < 16 MB.
-For larger d_in, ops.py falls back to the K-tiled fused_linear variant.
+Grid (M/bm, N/bn, K/bk), the K axis accumulating in a VMEM f32 scratch.
 """
 
 from __future__ import annotations
@@ -20,33 +22,45 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
 
 
-def _delta_kernel(x_ref, idx_ref, val_ref, y_ref, *, k: int):
-    x = x_ref[...]  # (bm, d_in)
-    idx = idx_ref[...]  # (k, bn) int32
-    val = val_ref[...]  # (k, bn)
-    acc = jnp.zeros(y_ref.shape, jnp.float32)
-    for j in range(k):  # k is static and small (1..~32)
-        xg = jnp.take(x, idx[j], axis=1)  # lane gather -> (bm, bn)
-        acc = acc + xg.astype(jnp.float32) * val[j].astype(jnp.float32)
-    y_ref[...] = acc.astype(y_ref.dtype)
+def pick_block(dim: int, cap: int = 512) -> int:
+    """Largest of 512/256/128, at most ``cap``, that tiles ``dim``; ``dim``
+    itself (a full block, always legal) when none does."""
+    return next((s for s in (512, 256, 128) if s <= cap and dim % s == 0), dim)
 
 
-def _dval_kernel(x_ref, idx_ref, dy_ref, dval_ref, *, k: int):
-    """dval[j, o] = Σ_m dy[m, o] · x[m, idx[j, o]], accumulated over M tiles."""
-    m_step = pl.program_id(1)
+def delta_tile(idx, val, k0, bk: int, dtype, stride: int = 1):
+    """(bk, bn) dense delta for source rows ``k0 + stride·r``, r < bk.
 
-    @pl.when(m_step == 0)
+    ``idx``/``val`` are the (k, bn) column tile of the bypass. Exact in
+    ``dtype`` when ``val`` is: each column holds its k distinct entries."""
+    rows = k0 + stride * jax.lax.broadcasted_iota(jnp.int32, (bk, idx.shape[1]), 0)
+    s = jnp.zeros(rows.shape, jnp.float32)
+    for j in range(idx.shape[0]):  # k is static and small (1..~32)
+        s = s + jnp.where(rows == idx[j:j + 1], val[j:j + 1].astype(jnp.float32), 0.0)
+    return s.astype(dtype)
+
+
+def _delta_kernel(x_ref, idx_ref, val_ref, y_ref, acc_ref, *, bk: int):
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
     def _init():
-        dval_ref[...] = jnp.zeros_like(dval_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]  # (bm, d_in)
-    idx = idx_ref[...]  # (k, bn)
-    dy = dy_ref[...].astype(jnp.float32)  # (bm, bn)
-    for j in range(k):
-        xg = jnp.take(x, idx[j], axis=1).astype(jnp.float32)  # (bm, bn)
-        dval_ref[j, :] += jnp.sum(xg * dy, axis=0)
+    x = x_ref[...]  # (bm, bk)
+    s = delta_tile(idx_ref[...], val_ref[...], kk * bk, bk, x.dtype)
+    acc_ref[...] += jnp.dot(x, s, preferred_element_type=jnp.float32)
+
+    @pl.when(kk == pl.num_programs(2) - 1)
+    def _flush():
+        y_ref[...] = acc_ref[...].astype(y_ref.dtype)
 
 
 def sparse_delta_pallas(
@@ -63,42 +77,51 @@ def sparse_delta_pallas(
     k, d_out = idx.shape
     bm = min(block_m, m)
     bn = min(block_n, d_out)
+    bk = pick_block(d_in)
     if m % bm or d_out % bn:
         raise ValueError(f"M={m}, d_out={d_out} must tile by ({bm}, {bn})")
-    grid = (m // bm, d_out // bn)
     return pl.pallas_call(
-        functools.partial(_delta_kernel, k=k),
-        grid=grid,
+        functools.partial(_delta_kernel, bk=bk),
+        grid=(m // bm, d_out // bn, d_in // bk),
         in_specs=[
-            pl.BlockSpec((bm, d_in), lambda i, j: (i, 0)),
-            pl.BlockSpec((k, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((k, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((k, bn), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((k, bn), lambda i, j, kk: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, d_out), x.dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=_SEMANTICS,
         interpret=interpret,
     )(x, idx, val)
 
 
-def _delta_batched_kernel(x_ref, idx_ref, val_ref, aid_ref, y_ref, *, k: int, n: int):
-    """Per-slot adapter selection: row m applies adapter aid[m]'s k bypasses.
+def _delta_batched_kernel(x_ref, idx_ref, val_ref, aid_ref, y_ref, acc_ref, *, bk: int):
+    """Per-row adapter selection: row m applies adapter aid[m]'s k bypasses.
 
-    N and k are static and small (tenant count × bypass count), so the
-    double loop unrolls into N·k lane gathers with a per-row select — no
-    per-sublane dynamic gather, which Mosaic handles poorly.
-    """
-    x = x_ref[...]  # (bm, d_in)
+    The adapter count N is static and small, so each cell contracts the
+    activation tile with each adapter's delta tile and keeps the rows
+    that adapter owns — no per-row dynamic gather."""
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    x = x_ref[...]  # (bm, bk)
     idx = idx_ref[...]  # (n, k, bn) int32
     val = val_ref[...]  # (n, k, bn)
     aid = aid_ref[...]  # (bm, 1) int32
-    acc = jnp.zeros(y_ref.shape, jnp.float32)
-    for a in range(n):
-        contrib = jnp.zeros(y_ref.shape, jnp.float32)
-        for j in range(k):
-            xg = jnp.take(x, idx[a, j], axis=1)  # lane gather -> (bm, bn)
-            contrib = contrib + xg.astype(jnp.float32) * val[a, j].astype(jnp.float32)
+    acc = acc_ref[...]
+    for a in range(idx.shape[0]):
+        s = delta_tile(idx[a], val[a], kk * bk, bk, x.dtype)
+        contrib = jnp.dot(x, s, preferred_element_type=jnp.float32)
         acc = acc + jnp.where(aid == a, contrib, 0.0)
-    y_ref[...] = acc.astype(y_ref.dtype)
+    acc_ref[...] = acc
+
+    @pl.when(kk == pl.num_programs(2) - 1)
+    def _flush():
+        y_ref[...] = acc_ref[...].astype(y_ref.dtype)
 
 
 def sparse_delta_batched_pallas(
@@ -116,22 +139,43 @@ def sparse_delta_batched_pallas(
     n_ad, k, d_out = idx.shape
     bm = min(block_m, m)
     bn = min(block_n, d_out)
+    bk = pick_block(d_in)
     if m % bm or d_out % bn:
         raise ValueError(f"M={m}, d_out={d_out} must tile by ({bm}, {bn})")
-    grid = (m // bm, d_out // bn)
     return pl.pallas_call(
-        functools.partial(_delta_batched_kernel, k=k, n=n_ad),
-        grid=grid,
+        functools.partial(_delta_batched_kernel, bk=bk),
+        grid=(m // bm, d_out // bn, d_in // bk),
         in_specs=[
-            pl.BlockSpec((bm, d_in), lambda i, j: (i, 0)),
-            pl.BlockSpec((n_ad, k, bn), lambda i, j: (0, 0, j)),
-            pl.BlockSpec((n_ad, k, bn), lambda i, j: (0, 0, j)),
-            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((n_ad, k, bn), lambda i, j, kk: (0, 0, j)),
+            pl.BlockSpec((n_ad, k, bn), lambda i, j, kk: (0, 0, j)),
+            pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, d_out), x.dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=_SEMANTICS,
         interpret=interpret,
     )(x, idx, val, aid[:, None])
+
+
+def _dval_kernel(x_ref, idx_ref, dy_ref, dval_ref, *, bk: int):
+    """dval[j, o] = Σ_m dy[m, o] · x[m, idx[j, o]], accumulated over the
+    K and M tiles: the gather is a one-hot contraction on the MXU."""
+    kk, mm = pl.program_id(1), pl.program_id(2)
+
+    @pl.when((kk == 0) & (mm == 0))
+    def _init():
+        dval_ref[...] = jnp.zeros_like(dval_ref)
+
+    x = x_ref[...]  # (bm, bk)
+    idx = idx_ref[...]  # (k, bn)
+    dy = dy_ref[...].astype(jnp.float32)  # (bm, bn)
+    rows = kk * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, idx.shape[1]), 0)
+    for j in range(idx.shape[0]):
+        onehot = (rows == idx[j:j + 1]).astype(x.dtype)  # (bk, bn)
+        xg = jnp.dot(x, onehot, preferred_element_type=jnp.float32)  # (bm, bn)
+        dval_ref[j:j + 1, :] += jnp.sum(xg * dy, axis=0, keepdims=True)
 
 
 def sparse_delta_dval_pallas(
@@ -148,19 +192,23 @@ def sparse_delta_dval_pallas(
     k, d_out = idx.shape
     bm = min(block_m, m)
     bn = min(block_n, d_out)
+    bk = pick_block(d_in)
     if m % bm or d_out % bn:
         raise ValueError(f"M={m}, d_out={d_out} must tile by ({bm}, {bn})")
-    # n-parallel outer, m-reduction inner (sequential accumulate).
-    grid = (d_out // bn, m // bm)
+    # n-parallel outer; the K and M reductions accumulate into the
+    # resident (k, bn) output block
     return pl.pallas_call(
-        functools.partial(_dval_kernel, k=k),
-        grid=grid,
+        functools.partial(_dval_kernel, bk=bk),
+        grid=(d_out // bn, d_in // bk, m // bm),
         in_specs=[
-            pl.BlockSpec((bm, d_in), lambda j, i: (i, 0)),
-            pl.BlockSpec((k, bn), lambda j, i: (0, j)),
-            pl.BlockSpec((bm, bn), lambda j, i: (i, j)),
+            pl.BlockSpec((bm, bk), lambda j, kk, i: (i, kk)),
+            pl.BlockSpec((k, bn), lambda j, kk, i: (0, j)),
+            pl.BlockSpec((bm, bn), lambda j, kk, i: (i, j)),
         ],
-        out_specs=pl.BlockSpec((k, bn), lambda j, i: (0, j)),
+        out_specs=pl.BlockSpec((k, bn), lambda j, kk, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((k, d_out), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+        ),
         interpret=interpret,
     )(x, idx, dy)

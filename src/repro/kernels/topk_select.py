@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+from repro.kernels.sparse_delta import pick_block
 
 _NEG = float("-inf")
 
@@ -60,13 +60,13 @@ def topk_select_pallas(
     w: jax.Array,
     k: int,
     *,
-    block_k: int = 1024,
+    block_k: int = 512,
     block_n: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
     """w (d_in, d_out) -> idx (k, d_out) int32 (unordered per column)."""
     d_in, d_out = w.shape
-    bk = min(block_k, d_in)
+    bk = pick_block(d_in, block_k)
     bn = min(block_n, d_out)
     if d_in % bk or d_out % bn:
         raise ValueError(f"{w.shape} must tile by ({bk}, {bn})")
@@ -81,7 +81,7 @@ def topk_select_pallas(
             pltpu.VMEM((k, bn), jnp.float32),
             pltpu.VMEM((k, bn), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

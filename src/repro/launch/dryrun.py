@@ -33,12 +33,11 @@ from repro.configs.registry import ARCH_IDS
 from repro.distributed import sharding as shd
 from repro.distributed.context import clear_activation_sharding, set_activation_sharding
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_parse import structural_costs
 from repro.models import get_model
 from repro.peft import get_peft
 from repro.train.trainer import TrainState, make_train_step
-
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
 
 # Tokens per device per microbatch the train dry-run aims for. The remat
 # h-stack is sequence-parallel (S/TP per device), so non-FSDP archs afford
@@ -236,6 +235,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=tuple(SHAPES))

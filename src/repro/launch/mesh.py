@@ -11,18 +11,26 @@ shard coherently across pods.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the sharding rules and the
+    vocab-sharded ``jnp.take`` leave placement to GSPMD, which the
+    installed JAX's default (explicit axes) refuses."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh():
     """Whatever devices exist (tests / CPU examples): 1-D data mesh."""
     n = jax.device_count()
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",))
 
 
 def make_serve_mesh(tp: int):
@@ -41,8 +49,8 @@ def make_serve_mesh(tp: int):
     if n % tp:
         raise ValueError(f"tp={tp} does not divide the {n} local devices")
     if tp == n:
-        return jax.make_mesh((tp,), ("model",))
-    return jax.make_mesh((n // tp, tp), ("data", "model"))
+        return make_mesh((tp,), ("model",))
+    return make_mesh((n // tp, tp), ("data", "model"))
 
 
 # TPU v5e structural constants for the roofline (DESIGN.md §5).

@@ -33,6 +33,9 @@ dump the run's metrics registry and request-lifecycle trace on exit;
 steps; ``--profile-dir d/`` wraps the run in a ``jax.profiler`` trace
 capture for TensorBoard/XProf. All of it is host-side — the one
 device→host transfer per megastep is unchanged.
+
+A batch run's ``main`` returns its finished requests (tenant, prompt,
+output tokens, end reason) so callers can check what was served.
 """
 
 from __future__ import annotations
@@ -43,6 +46,7 @@ import os
 import jax
 
 from repro.configs import ARCH_IDS, PAPER_ARCH_IDS, get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.peft import BASE_DTYPES
 from repro.serve import AdapterStore, ServeEngine
@@ -173,6 +177,7 @@ def _metrics_line(engine, step: int) -> str:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b",
                     choices=ARCH_IDS + PAPER_ARCH_IDS)
@@ -387,6 +392,7 @@ def main(argv=None):
               f"accepted={engine.spec_accepted} ({rate:.0%}) "
               f"emitted={engine.spec_emitted}")
     _dump_obs(engine, tracer, args)
+    return reqs
 
 
 def _dump_obs(engine, tracer, args) -> None:

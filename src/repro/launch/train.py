@@ -1,9 +1,10 @@
 """Training launcher: the production entry point.
 
-Single-host CPU runs execute directly; on a TPU pod slice each host runs
-this same script (jax.distributed initializes from the TPU environment)
-and the data loader shards by host automatically. NeuroAda is the default
-PEFT; any method from peft/api.py is selectable.
+One process drives the local devices (CPU, or one TPU host); the data
+loader takes its host index and count from ``jax.process_index()`` /
+``jax.process_count()``. NeuroAda is the default PEFT; any method from
+peft/api.py is selectable. ``main`` returns the per-step history (loss,
+grad norm, …) so callers can check the run.
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --reduced \
       --task reasoning --steps 200 --peft neuroada --k 1 \
@@ -19,6 +20,7 @@ import jax
 
 from repro.configs import ARCH_IDS, PAPER_ARCH_IDS, PeftConfig, TrainConfig, get_config, reduced
 from repro.data.loader import DataLoader
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.peft import BASE_DTYPES, get_peft, stats
 from repro.train.trainer import Trainer
@@ -31,7 +33,7 @@ def parse_args(argv=None):
     ap.add_argument("--arch", default="qwen2-1.5b",
                     choices=ARCH_IDS + PAPER_ARCH_IDS)
     ap.add_argument("--reduced", action="store_true",
-                    help="CPU-sized family member (full configs need a pod)")
+                    help="CPU-sized family member for tests and rehearsals")
     ap.add_argument("--peft", default="neuroada",
                     choices=("neuroada", "lora", "bitfit", "masked", "full"))
     ap.add_argument("--base-dtype", default="fp32", choices=BASE_DTYPES,
@@ -62,6 +64,7 @@ def parse_args(argv=None):
 
 
 def main(argv=None):
+    enable_compile_cache()
     logging.basicConfig(level=logging.INFO, format="%(message)s")
     args = parse_args(argv)
     cfg = get_config(args.arch)

@@ -2,6 +2,8 @@
 so the fake device count never leaks into other tests)."""
 
 import json
+import os
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -26,6 +28,7 @@ class FakeMesh:
 
 
 MESH = FakeMesh()
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[2]
 
 
 def test_col_row_rules_fsdp():
@@ -109,7 +112,8 @@ _SUBPROC = textwrap.dedent(
     from repro.distributed import sharding as shd
     from repro.data.loader import peek_batch
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = reduced(get_config("qwen2-1.5b")).replace(d_model=64, vocab_size=512)
     m = get_model(cfg)
     params = m.init(jax.random.PRNGKey(0))
@@ -147,9 +151,9 @@ def test_8device_pjit_matches_single_device():
     proc = subprocess.run(
         [sys.executable, "-c", _SUBPROC],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo",
+        env={"PYTHONPATH": "src", "PATH": os.environ.get("PATH", ""),
+             "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"},
+        cwd=REPO_ROOT,
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")][0]
@@ -217,7 +221,9 @@ def test_param_shardings_quantized_base():
     from repro.distributed.sharding import param_shardings
     from repro.quant.qtensor import quantize
 
-    mesh = jax.make_mesh((jax.device_count(),), ("model",))
+    from repro.launch.mesh import make_serve_mesh
+
+    mesh = make_serve_mesh(jax.device_count())
     w = np.random.default_rng(0).standard_normal((64, 32)).astype(np.float32)
     params = {"blocks": {"wq": {"w": quantize(jax.numpy.asarray(w), "int8", block=16)}}}
     sh = param_shardings(params, mesh, "dense", fsdp=False)

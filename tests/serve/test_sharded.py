@@ -10,6 +10,8 @@ slow full parity grid: tp ∈ {1, 2, 4} × paged/dense × plain/multitenant
 """
 
 import json
+import os
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -18,15 +20,16 @@ import pytest
 
 from repro.launch.mesh import make_serve_mesh
 
-_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-        "HOME": "/root", "JAX_PLATFORMS": "cpu"}
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[2]
+_ENV = {"PYTHONPATH": "src", "PATH": os.environ.get("PATH", ""),
+        "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"}
 
 
 def _run(script: str, timeout: int = 600) -> dict:
     proc = subprocess.run(
         [sys.executable, "-c", script],
         capture_output=True, text=True, timeout=timeout,
-        env=_ENV, cwd="/root/repo",
+        env=_ENV, cwd=REPO_ROOT,
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")][0]
