@@ -58,7 +58,7 @@ import numpy as np
 
 from benchmarks.common import bench_model
 from repro.core.adapt import init_adapters, merge_adapters
-from repro.obs import Tracer, percentile
+from repro.obs import percentile
 from repro.serve import AdapterStore, QueueFullError, ServeEngine
 
 MAX_LEN = 128
@@ -321,7 +321,6 @@ def run(*, steps: int = 24) -> list[str]:
     mixed = _mixed_workload(m, params, out)
     capacity = _capacity_demo(m, params, out)
     quant_kv = _quant_kv_section(out, steps=steps)
-    observability = _obs_overhead(m, params, out)
     lifecycle = _lifecycle_section(m, params, out)
     sharded = _sharded_section(out)
 
@@ -331,7 +330,7 @@ def run(*, steps: int = 24) -> list[str]:
          "paged_vs_dense": paged_ratios, "speculative": spec_records,
          "mixed_workload": mixed, "capacity": capacity,
          "quant_kv": quant_kv,
-         "observability": observability, "lifecycle": lifecycle,
+         "lifecycle": lifecycle,
          "sharded": sharded},
         indent=2,
     ))
@@ -644,73 +643,6 @@ def _quant_kv_section(out, *, steps):
             "pool_bytes_int8": eng_i8.kv.pool_bytes(),
             "pool_bytes_fp32_equiv": eng_fp.kv.pool_bytes(),
         },
-    }
-
-
-def _obs_overhead(m, params, out):
-    """Observability overhead budget (DESIGN §13): the slots=4/chunk=8
-    paged column with metrics AND request tracing enabled against its
-    ``metrics=False`` (NullRegistry, no tracer) twin. Both engines warm
-    up once, then alternate timed windows (on, off, on, …) so box-load
-    drift hits both equally; each side's min-wall window is its
-    structural cost. The contract is ≤3% tok/s: instrumentation is a few
-    pre-bound float adds per step on a path whose unit of work is a
-    compiled megastep. The ON engine's transfer counter is asserted
-    equal to its compiled-step count — observability rides the existing
-    device→host fetch (the OFF twin's NullRegistry reads 0 by design,
-    so the invariant is pinned against step calls, not the twin)."""
-    def make(obs_on):
-        eng = ServeEngine(
-            m, params, slots=4, max_len=MAX_LEN, decode_chunk=8,
-            eos_id=1 << 20, paged=True,
-            metrics=obs_on, tracer=Tracer() if obs_on else None,
-        )
-        for i in range(4):
-            eng.submit([1, 3 + i, 7, 2 + i], max_new=MAX_LEN - 8)
-        reqs = eng.scheduler.in_flight()
-        steps = 1
-        eng.step()  # admit + prefill (compiles the mixed step)
-        while eng.scheduler.has_prefilling():
-            eng.step()
-            steps += 1
-        eng.step()  # compile the decode megastep outside the windows
-        return [eng, reqs, steps + 1]
-
-    engines = {flag: make(flag) for flag in (True, False)}
-    n_calls, best = 2, {}
-    for _ in range(5):  # interleaved windows, best-of per side
-        for flag, ent in engines.items():
-            eng, reqs, _ = ent
-            tok0 = sum(len(r.out) for r in reqs)
-            t0 = time.perf_counter()
-            for _ in range(n_calls):
-                eng.step()
-            wall = time.perf_counter() - t0
-            ent[2] += n_calls
-            toks = sum(len(r.out) for r in reqs) - tok0
-            if toks and (flag not in best or wall < best[flag][0]):
-                best[flag] = (wall, toks)
-    tok_s = {f: t / w for f, (w, t) in best.items()}
-    (eng_on, _, steps_on), (eng_off, _, steps_off) = (
-        engines[True], engines[False],
-    )
-    assert steps_on == steps_off, (steps_on, steps_off)
-    assert eng_on.transfers == steps_on, (eng_on.transfers, steps_on)
-    ratio = tok_s[True] / tok_s[False]
-    out.append(
-        f"serve.obs.overhead,0,on={tok_s[True]:.1f}_off={tok_s[False]:.1f}"
-        f"_ratio={ratio:.3f}"
-    )
-    return {
-        "slots": 4, "chunk": 8, "cache": "paged",
-        "tok_s_metrics_on": round(tok_s[True], 1),
-        "tok_s_metrics_off": round(tok_s[False], 1),
-        "overhead_ratio": round(ratio, 3),
-        "budget": "metrics+trace within 3% of NullRegistry baseline",
-        "compiled_steps": steps_on,
-        "transfers_on": eng_on.transfers,
-        "trace_events": len(eng_on.tracer),
-        "metric_series": len(eng_on.metrics.snapshot()),
     }
 
 

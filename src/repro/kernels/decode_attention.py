@@ -210,6 +210,7 @@ def decode_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
         compiler_params=SEMANTICS,
         interpret=interpret,
+        name="decode_attn",
     )(*prefetch, q.reshape(b, hkv, g, hd), lane_view(k), lane_view(v))
     return out.reshape(b, 1, h, hd)
 
@@ -319,6 +320,7 @@ def paged_decode_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
         compiler_params=SEMANTICS,
         interpret=interpret,
+        name="paged_decode_attn",
     )(*prefetch, q.reshape(b, hkv, g, hd), lane_view(k_pool), lane_view(v_pool))
     return out.reshape(b, 1, h, hd)
 

@@ -114,6 +114,7 @@ def flash_attention_fwd_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_attn_fwd",
     )(q, k, v)
 
 

@@ -87,4 +87,5 @@ def fused_linear_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="fused_linear",
     )(x, w, idx, val, b)

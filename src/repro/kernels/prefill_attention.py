@@ -156,6 +156,7 @@ def paged_prefill_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((b, hkv, c * g, hd), q.dtype),
         compiler_params=SEMANTICS,
         interpret=interpret,
+        name="paged_prefill_attn",
     )(*prefetch, qg, lane_view(k_pool), lane_view(v_pool))
     out = out.reshape(b, hkv, c, g, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(b, c, h, hd)

@@ -142,6 +142,7 @@ def fused_linear_q_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="fused_linear_q",
     )(*xs, data, scales.reshape(kdim // bk, bk // block, n), idx, val, b)
 
 

@@ -93,6 +93,7 @@ def sparse_delta_pallas(
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="sparse_delta",
     )(x, idx, val)
 
 
@@ -156,6 +157,7 @@ def sparse_delta_batched_pallas(
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="sparse_delta_batched",
     )(x, idx, val, aid[:, None])
 
 
@@ -211,4 +213,5 @@ def sparse_delta_dval_pallas(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
+        name="delta_dval",
     )(x, idx, dy)

@@ -85,4 +85,5 @@ def topk_select_pallas(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="topk_select",
     )(w)

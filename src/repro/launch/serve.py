@@ -31,7 +31,9 @@ trace-event JSON, Perfetto-loadable; ``.jsonl`` for line-delimited)
 dump the run's metrics registry and request-lifecycle trace on exit;
 ``--metrics-every N`` prints a one-line metrics digest every N serve
 steps; ``--profile-dir d/`` wraps the run in a ``jax.profiler`` trace
-capture for TensorBoard/XProf. All of it is host-side — the one
+capture for TensorBoard/XProf, where the engine's phase spans
+(``serve.step`` and its phases) lie beside the device's operations and
+each Pallas kernel carries its name. All of it is host-side — the one
 device→host transfer per megastep is unchanged.
 
 A batch run's ``main`` returns its finished requests (tenant, prompt,

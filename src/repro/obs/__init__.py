@@ -11,7 +11,11 @@ clock   — the ONE monotonic source every lifecycle timestamp routes
           through (``obs.now``): Request stamps, TTFT/ITL observation,
           deadline arithmetic, rate-limit refills and trace timestamps
           all read the same clock, so histograms and spans agree
-          exactly (DESIGN §16).
+          exactly (DESIGN §16);
+spans   — the phase names of a serving and a training step and
+          ``span(name, **counts)``, a profiler ``TraceAnnotation`` on
+          the device trace's clock. The one module here that imports
+          jax, so it is imported on its own (``repro.obs.spans``).
 
 Everything is host-side python over state the engine already fetched:
 instrumentation adds zero device→host transfers (the transfer-counting

@@ -21,9 +21,7 @@ label values):
   ``BENCH_serving.json`` and the smoke validator consume.
 
 :class:`NullRegistry` is the metrics-off twin: it hands out no-op
-instruments with the same API so instrumented code needs no branches,
-and is how the ≤3% overhead budget is benched (``bench_serving``'s
-observability leg).
+instruments with the same API so instrumented code needs no branches.
 
 :func:`percentile` is the one exact-percentile implementation in the
 repo — ``bench_serving`` TTFT/ITL columns and the engine tests both rank

@@ -76,7 +76,10 @@ transfer-counting tests run with metrics and tracing enabled — and it
 adds no traced inputs, so the compiled graphs are byte-identical with
 observability on or off (the compile-count regression test pins that).
 ``metrics=False`` swaps in the no-op registry; ``tracer=None`` (the
-default) skips lifecycle tracing entirely.
+default) skips lifecycle tracing entirely. ``step`` also marks its
+phases (expire, admit, reserve, plan, put, dispatch, fetch, emit,
+gauges) with profiler spans (``repro.obs.spans``), recorded whenever a
+profiler runs and about a microsecond each when none does.
 """
 
 from __future__ import annotations
@@ -90,6 +93,8 @@ import numpy as np
 import repro.obs.clock as _clock
 from repro.core.delta import BatchedDelta
 from repro.obs import MetricsRegistry, NullRegistry, Tracer
+from repro.obs import spans
+from repro.obs.spans import span
 from repro.serve.adapters import AdapterStore
 from repro.serve.kv_cache import KV_DTYPES, DraftKVCache, KVCache, PagedKVCache
 from repro.serve.sampler import Sampler
@@ -1390,24 +1395,36 @@ class ServeEngine:
         prefill+decode step (one prompt chunk under the token budget,
         one token per decode slot); otherwise it is a decode megastep
         over up to ``decode_chunk`` tokens. Either way: one jitted call,
-        one device→host transfer.
+        one device→host transfer. ``serve_step_seconds`` observes the
+        whole call, the interval of its ``serve.step`` span.
         """
+        t_call = self.clock()
+        with span(spans.SERVE_STEP):
+            kind = self._step()
+            if kind is not None:
+                self._h_step[kind].observe(self.clock() - t_call)
+        return kind is not None
+
+    def _step(self) -> str | None:
+        """The body of :meth:`step`: its kind, or None when idle."""
         self.rng, k_step = jax.random.split(self.rng)
         if self.chaos is not None:
             # faults land at the exact boundary real ones do: before the
             # sweep (a stormed deadline expires THIS step) and before
             # admission (stolen pool blocks refuse placements THIS step)
             self.chaos.on_step(self)
-        self._expire_deadlines()
-        self._check_adapter_ids()
-        self._admit()
+        with span(spans.SERVE_EXPIRE):
+            self._expire_deadlines()
+        with span(spans.SERVE_ADMIT):
+            self._check_adapter_ids()
+            self._admit()
         if not self.scheduler.has_active():
             if self.chaos is not None:
                 # this step's own injections may have just terminated the
                 # last request; hand any stolen pool blocks back before
                 # reporting idle (nobody will call step() again)
                 self.chaos.release(self)
-            return False
+            return None
         t0 = self.clock()
         if self.scheduler.has_prefilling():
             kind = "mixed"
@@ -1421,7 +1438,6 @@ class ServeEngine:
         # step accounting is pure host arithmetic on the clocks and
         # free-lists the step already maintained — no device traffic
         dt = self.clock() - t0
-        self._h_step[kind].observe(dt)
         self._c_step[kind].inc()
         # EMA of compiled-step wall time feeds deadline-aware admission:
         # a request whose deadline cannot cover even one more step is
@@ -1434,8 +1450,9 @@ class ServeEngine:
             self.step_seconds_ema = dt
         elif dt < 10.0 * self.step_seconds_ema:
             self.step_seconds_ema = 0.9 * self.step_seconds_ema + 0.1 * dt
-        self._update_gauges()
-        return True
+        with span(spans.SERVE_GAUGES):
+            self._update_gauges()
+        return kind
 
     # ------------------------------------------------- mixed chunk step
 
@@ -1446,17 +1463,26 @@ class ServeEngine:
         lifecycle and register freshly written prefix pages for dedup."""
         tr0 = self.tracer.now() if self.tracer is not None else 0.0
         if self.paged:
-            self._reserve(1)
-        plan = self.scheduler.chunk_plan(self.prefill_chunk, self.kv.pos_host)
-        stacked = self._stacked()
+            with span(spans.SERVE_RESERVE):
+                self._reserve(1)
+        with span(spans.SERVE_PLAN) as sp:
+            plan = self.scheduler.chunk_plan(self.prefill_chunk, self.kv.pos_host)
+            stacked = self._stacked()
+            # the buffer's rows against the rows that hold a real token
+            sp.set_metadata(
+                rows=plan["tokens"].size, real_rows=int(plan["q_len"].sum())
+            )
         spec = self.draft_kv is not None  # ngram prefills like plain
-        lead = [self.params]
-        if spec:
-            lead.append(self.draft_params)
-        if stacked is not None:
-            lead += [*stacked, jnp.asarray(plan["aid"])]
-        if self.paged:
-            lead += [self.kv.table_device(), self.kv.write_table_device()]
+        with span(spans.SERVE_PUT):
+            lead = [self.params]
+            if spec:
+                lead.append(self.draft_params)
+            if stacked is not None:
+                lead += [*stacked, jnp.asarray(plan["aid"])]
+            if self.paged:
+                lead += [self.kv.table_device(), self.kv.write_table_device()]
+            inputs = [jnp.asarray(plan[k]) for k in
+                      ("tokens", "q_offset", "q_len", "last_idx", "temps")]
         caches = [self.kv.data, self.draft_kv.data] if spec else [self.kv.data]
         fn = getattr(
             self,
@@ -1464,11 +1490,8 @@ class ServeEngine:
             + ("_paged" if self.paged else "")
             + ("_ad" if stacked is not None else "_plain"),
         )
-        out = fn(
-            *lead, *caches, jnp.asarray(plan["tokens"]),
-            jnp.asarray(plan["q_offset"]), jnp.asarray(plan["q_len"]),
-            jnp.asarray(plan["last_idx"]), jnp.asarray(plan["temps"]), key,
-        )
+        with span(spans.SERVE_DISPATCH):
+            out = fn(*lead, *caches, *inputs, key)
         if spec:
             self.kv.data, self.draft_kv.data, pos_dev, toks_dev = out
         else:
@@ -1476,34 +1499,37 @@ class ServeEngine:
         # ONE device→host transfer for the whole mixed step: the sampled
         # token vector. Positions advance deterministically to
         # q_offset + q_len, so the host mirrors them without a fetch.
-        toks = jax.device_get(toks_dev)
-        self._c_transfers.inc()
-        self.kv.sync(pos_dev, plan["q_offset"] + plan["q_len"])
-        now = self.clock()
-        tr1 = self.tracer.now() if self.tracer is not None else 0.0
-        n_emit = 0
-        for s, req in enumerate(self.scheduler.active):
-            if req is None:
-                continue
-            take = int(plan["q_len"][s])
-            if take and req.mid_prefill:
-                if self.tracer is not None:
-                    self.tracer.span(
-                        req.rid, "prefill_chunk", tr0, tr1, tokens=take,
-                        offset=int(plan["q_offset"][s]),
-                    )
-                req.prefilled += take
-                if self.paged:
-                    self.kv.mark_prefilled(s, req.prefilled)
-            elif take and self.tracer is not None:
-                # decode slot riding the mixed step as a one-token chunk
-                self.tracer.span(req.rid, "decode", tr0, tr1, tokens=1,
-                                 mixed=True)
-            if plan["emit"][s]:
-                n_emit += 1
-                self._emit_token(req, int(toks[s]), "mixed", now)
-                self._maybe_finish(s, req)
-        self._c_tokens["mixed"].inc(n_emit)
+        with span(spans.SERVE_FETCH):
+            toks = jax.device_get(toks_dev)
+        with span(spans.SERVE_EMIT) as sp:
+            self._c_transfers.inc()
+            self.kv.sync(pos_dev, plan["q_offset"] + plan["q_len"])
+            now = self.clock()
+            tr1 = self.tracer.now() if self.tracer is not None else 0.0
+            n_emit = 0
+            for s, req in enumerate(self.scheduler.active):
+                if req is None:
+                    continue
+                take = int(plan["q_len"][s])
+                if take and req.mid_prefill:
+                    if self.tracer is not None:
+                        self.tracer.span(
+                            req.rid, "prefill_chunk", tr0, tr1, tokens=take,
+                            offset=int(plan["q_offset"][s]),
+                        )
+                    req.prefilled += take
+                    if self.paged:
+                        self.kv.mark_prefilled(s, req.prefilled)
+                elif take and self.tracer is not None:
+                    # decode slot riding the mixed step as a one-token chunk
+                    self.tracer.span(req.rid, "decode", tr0, tr1, tokens=1,
+                                     mixed=True)
+                if plan["emit"][s]:
+                    n_emit += 1
+                    self._emit_token(req, int(toks[s]), "mixed", now)
+                    self._maybe_finish(s, req)
+            self._c_tokens["mixed"].inc(n_emit)
+            sp.set_metadata(emitted=n_emit)
 
     def _decode_horizon(self) -> int:
         """Worst-case per-megastep position advance of one decode slot:
@@ -1571,55 +1597,60 @@ class ServeEngine:
         ``decode_chunk`` tokens per slot in one compiled call."""
         tr0 = self.tracer.now() if self.tracer is not None else 0.0
         if self.paged:
-            self._reserve(self.decode_chunk)
-        st = self.scheduler.slot_arrays()
-        stacked = self._stacked()
-        args = (
-            self.kv.data, jnp.asarray(st["tokens"]), self.kv.pos,
-            jnp.asarray(st["active"]), jnp.asarray(st["remaining"]),
-            jnp.asarray(st["temps"]), key,
-        )
-        if self.paged:
-            args = (self.kv.table_device(),) + args
-            if stacked is None:
-                out = self._megastep_paged_plain(self.params, *args)
-            else:
-                out = self._megastep_paged_ad(
-                    self.params, *stacked, jnp.asarray(st["aid"]), *args
-                )
-        elif stacked is None:
-            out = self._megastep_plain(self.params, *args)
-        else:
-            out = self._megastep_ad(
-                self.params, *stacked, jnp.asarray(st["aid"]), *args
+            with span(spans.SERVE_RESERVE):
+                self._reserve(self.decode_chunk)
+        with span(spans.SERVE_PLAN):
+            st = self.scheduler.slot_arrays()
+            stacked = self._stacked()
+        with span(spans.SERVE_PUT):
+            args = (
+                self.kv.data, jnp.asarray(st["tokens"]), self.kv.pos,
+                jnp.asarray(st["active"]), jnp.asarray(st["remaining"]),
+                jnp.asarray(st["temps"]), key,
             )
+            if self.paged:
+                args = (self.kv.table_device(),) + args
+            if stacked is not None:
+                args = (*stacked, jnp.asarray(st["aid"])) + args
+        fn = getattr(
+            self,
+            "_megastep"
+            + ("_paged" if self.paged else "")
+            + ("_ad" if stacked is not None else "_plain"),
+        )
+        with span(spans.SERVE_DISPATCH):
+            out = fn(self.params, *args)
         self.kv.data, pos_dev = out[0], out[1]
         # ONE device→host transfer for the whole chunk (all slots, all
         # steps): emitted tokens + mask, final positions, survivor mask.
-        pos_np, active_np, toks, emits = jax.device_get(out[1:])
-        self._c_transfers.inc()
-        now = self.clock()
-        tr1 = self.tracer.now() if self.tracer is not None else 0.0
-        self.kv.sync(pos_dev, pos_np)
-        n_emit = 0
-        for t in range(self.decode_chunk):
+        with span(spans.SERVE_FETCH):
+            pos_np, active_np, toks, emits = jax.device_get(out[1:])
+        with span(spans.SERVE_EMIT) as sp:
+            self._c_transfers.inc()
+            now = self.clock()
+            tr1 = self.tracer.now() if self.tracer is not None else 0.0
+            self.kv.sync(pos_dev, pos_np)
+            n_emit = 0
+            for t in range(self.decode_chunk):
+                for s, req in enumerate(self.scheduler.active):
+                    if req is not None and emits[t, s]:
+                        self._emit_token(req, int(toks[t, s]), "decode", now)
+                        n_emit += 1
+            self._c_tokens["decode"].inc(n_emit)
+            if self.tracer is not None:
+                for s, req in enumerate(self.scheduler.active):
+                    if req is not None:
+                        self.tracer.span(
+                            req.rid, "decode", tr0, tr1,
+                            tokens=int(emits[:, s].sum()),
+                        )
             for s, req in enumerate(self.scheduler.active):
-                if req is not None and emits[t, s]:
-                    self._emit_token(req, int(toks[t, s]), "decode", now)
-                    n_emit += 1
-        self._c_tokens["decode"].inc(n_emit)
-        if self.tracer is not None:
-            for s, req in enumerate(self.scheduler.active):
-                if req is not None:
-                    self.tracer.span(
-                        req.rid, "decode", tr0, tr1,
-                        tokens=int(emits[:, s].sum()),
-                    )
-        for s, req in enumerate(self.scheduler.active):
-            if req is not None and not active_np[s]:
-                # the in-graph mask already encodes EOS/max_new/cache-full;
-                # completing off it keeps host and device lifecycles identical
-                self._finish(s, req)
+                if req is not None and not active_np[s]:
+                    # the in-graph mask already encodes EOS/max_new/cache-
+                    # full; completing off it keeps host and device
+                    # lifecycles identical
+                    self._finish(s, req)
+            sp.set_metadata(emitted=n_emit)
 
     def _spec_decode_step(self, key) -> None:
         """One speculative decode megastep (DESIGN §12): ``decode_chunk``
@@ -1629,40 +1660,46 @@ class ServeEngine:
         (chunk, slots) matrix."""
         tr0 = self.tracer.now() if self.tracer is not None else 0.0
         if self.paged:
-            self._reserve(self._decode_horizon())
-        st = self.scheduler.slot_arrays()
-        stacked = self._stacked()
+            with span(spans.SERVE_RESERVE):
+                self._reserve(self._decode_horizon())
         ngram = self.draft == "ngram"
-        lead = [self.params] if ngram else [self.params, self.draft_params]
-        if stacked is not None:
-            lead += [*stacked, jnp.asarray(st["aid"])]
-        if self.paged:
-            lead.append(self.kv.table_device())
+        with span(spans.SERVE_PLAN):
+            st = self.scheduler.slot_arrays()
+            stacked = self._stacked()
+            if ngram:
+                # rebuild the token history on the host: hist[s, :len(seq)]
+                # is the committed sequence, and pos[s] == len(seq) - 1 at
+                # every decode boundary (the current input token is
+                # seq[-1]) — the invariant the in-graph matcher and
+                # appender rely on
+                hist = np.zeros((self.slots, self.max_len), np.int32)
+                for s, req in enumerate(self.scheduler.active):
+                    if req is not None:
+                        seq = req.prompt + req.out
+                        hist[s, : len(seq)] = seq
+        with span(spans.SERVE_PUT):
+            lead = [self.params] if ngram else [self.params, self.draft_params]
+            if stacked is not None:
+                lead += [*stacked, jnp.asarray(st["aid"])]
+            if self.paged:
+                lead.append(self.kv.table_device())
+            if ngram:
+                caches = [self.kv.data, jnp.asarray(hist)]
+            else:
+                caches = [self.kv.data, self.draft_kv.data]
+            inputs = (
+                jnp.asarray(st["tokens"]), self.kv.pos,
+                jnp.asarray(st["active"]), jnp.asarray(st["remaining"]),
+                jnp.asarray(st["temps"]), key,
+            )
         fn = getattr(
             self,
             ("_ngram_megastep" if ngram else "_spec_megastep")
             + ("_paged" if self.paged else "")
             + ("_ad" if stacked is not None else "_plain"),
         )
-        if ngram:
-            # rebuild the token history on the host: hist[s, :len(seq)] is
-            # the committed sequence, and pos[s] == len(seq) - 1 at every
-            # decode boundary (the current input token is seq[-1]) — the
-            # invariant the in-graph matcher and appender rely on
-            hist = np.zeros((self.slots, self.max_len), np.int32)
-            for s, req in enumerate(self.scheduler.active):
-                if req is not None:
-                    seq = req.prompt + req.out
-                    hist[s, : len(seq)] = seq
-            caches = [self.kv.data, jnp.asarray(hist)]
-        else:
-            caches = [self.kv.data, self.draft_kv.data]
-        out = fn(
-            *lead, *caches,
-            jnp.asarray(st["tokens"]), self.kv.pos,
-            jnp.asarray(st["active"]), jnp.asarray(st["remaining"]),
-            jnp.asarray(st["temps"]), key,
-        )
+        with span(spans.SERVE_DISPATCH):
+            out = fn(*lead, *caches, *inputs)
         if ngram:
             self.kv.data, pos_dev = out[0], out[1]
             fetched = out[1:]
@@ -1672,46 +1709,49 @@ class ServeEngine:
         # still ONE device→host transfer for the whole megastep: positions,
         # survivor mask, candidate tokens + emit mask, acceptance counts,
         # round-entry live masks — one fetch of the bundle
-        pos_np, active_np, toks, emits, accs, lives = jax.device_get(fetched)
-        self._c_transfers.inc()
-        now = self.clock()
-        tr1 = self.tracer.now() if self.tracer is not None else 0.0
-        self.kv.sync(pos_dev, pos_np)
-        n_emit = 0
-        slot_rounds = [0] * self.slots
-        slot_tokens = [0] * self.slots
-        slot_accepted = [0] * self.slots
-        for r in range(self.decode_chunk):
+        with span(spans.SERVE_FETCH):
+            pos_np, active_np, toks, emits, accs, lives = jax.device_get(fetched)
+        with span(spans.SERVE_EMIT) as sp:
+            self._c_transfers.inc()
+            now = self.clock()
+            tr1 = self.tracer.now() if self.tracer is not None else 0.0
+            self.kv.sync(pos_dev, pos_np)
+            n_emit = 0
+            slot_rounds = [0] * self.slots
+            slot_tokens = [0] * self.slots
+            slot_accepted = [0] * self.slots
+            for r in range(self.decode_chunk):
+                for s, req in enumerate(self.scheduler.active):
+                    if req is None:
+                        continue
+                    if lives[r, s]:
+                        acc = int(accs[r, s])
+                        req.spec_drafted += self.spec_k
+                        req.spec_accepted += acc
+                        self._c_spec_drafted.inc(self.spec_k)
+                        self._c_spec_accepted.inc(acc)
+                        self._h_spec_accept.observe(acc)
+                        slot_rounds[s] += 1
+                        slot_accepted[s] += acc
+                    for j in range(self.spec_k + 1):
+                        if emits[r, s, j]:
+                            self._emit_token(req, int(toks[r, s, j]), "spec", now)
+                            self._c_spec_emitted.inc()
+                            n_emit += 1
+                            slot_tokens[s] += 1
+            self._c_tokens["spec"].inc(n_emit)
+            if self.tracer is not None:
+                for s, req in enumerate(self.scheduler.active):
+                    if req is not None:
+                        self.tracer.span(
+                            req.rid, "spec_round", tr0, tr1,
+                            rounds=slot_rounds[s], accepted=slot_accepted[s],
+                            tokens=slot_tokens[s],
+                        )
             for s, req in enumerate(self.scheduler.active):
-                if req is None:
-                    continue
-                if lives[r, s]:
-                    acc = int(accs[r, s])
-                    req.spec_drafted += self.spec_k
-                    req.spec_accepted += acc
-                    self._c_spec_drafted.inc(self.spec_k)
-                    self._c_spec_accepted.inc(acc)
-                    self._h_spec_accept.observe(acc)
-                    slot_rounds[s] += 1
-                    slot_accepted[s] += acc
-                for j in range(self.spec_k + 1):
-                    if emits[r, s, j]:
-                        self._emit_token(req, int(toks[r, s, j]), "spec", now)
-                        self._c_spec_emitted.inc()
-                        n_emit += 1
-                        slot_tokens[s] += 1
-        self._c_tokens["spec"].inc(n_emit)
-        if self.tracer is not None:
-            for s, req in enumerate(self.scheduler.active):
-                if req is not None:
-                    self.tracer.span(
-                        req.rid, "spec_round", tr0, tr1,
-                        rounds=slot_rounds[s], accepted=slot_accepted[s],
-                        tokens=slot_tokens[s],
-                    )
-        for s, req in enumerate(self.scheduler.active):
-            if req is not None and not active_np[s]:
-                self._finish(s, req)
+                if req is not None and not active_np[s]:
+                    self._finish(s, req)
+            sp.set_metadata(emitted=n_emit)
 
     def _maybe_finish(self, slot: int, req: Request) -> None:
         if (

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import TrainConfig
 from repro.distributed.fault import NanGuard, StragglerMonitor
+from repro.obs import spans
+from repro.obs.spans import span
 from repro.optim import adamw, apply_updates, clip_by_global_norm, get_schedule
 
 log = logging.getLogger("repro.train")
@@ -201,14 +203,30 @@ class Trainer:
 
     def run(self, data_iter, steps: int | None = None) -> list[dict]:
         steps = steps if steps is not None else self.tcfg.steps
-        start = int(self.state.step)
+        start = int(jax.device_get(self.state.step))
         for i in range(start, steps):
+            with span(spans.TRAIN_STEP):
+                self._step(i, data_iter)
+        if self.ckpt is not None:
+            self.save(steps)
+            self.ckpt.wait()
+        return self.history
+
+    def _step(self, i: int, data_iter) -> None:
+        """Step ``i``: one compiled call and ONE device→host fetch, of the
+        step's metrics dict."""
+        with span(spans.TRAIN_FEED):
             batch = next(data_iter)
+        with span(spans.TRAIN_PUT):
             batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            self.monitor.start()
+        self.monitor.start()
+        with span(spans.TRAIN_DISPATCH):
             self.state, metrics = self._step_fn(
                 self.params, self.aux, self.state, batch
             )
+        with span(spans.TRAIN_FETCH):
+            metrics = jax.device_get(metrics)
+        with span(spans.TRAIN_LOG):
             metrics = {k: float(v) for k, v in metrics.items()}
             slow = self.monitor.stop(i)
             self.nan_guard.record(bool(metrics["skipped"]))
@@ -229,10 +247,6 @@ class Trainer:
                 and (i + 1) % self.tcfg.checkpoint_every == 0
             ):
                 self.save(i + 1)
-        if self.ckpt is not None:
-            self.save(steps)
-            self.ckpt.wait()
-        return self.history
 
     def save(self, step: int):
         self.ckpt.save(

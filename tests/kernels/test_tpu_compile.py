@@ -8,7 +8,9 @@ overruns). Each case lowers the ``ops`` wrapper under the ``pallas``
 backend at qwen2-1.5b widths (d 1536, 12/2 heads of 128, d_ff 8960, bf16)
 and the shapes the training and serving entry points give it: batch 4 ×
 seq 512 for training, 8 slots × max_len 2048 in 16-token pages with a
-256-token prefill chunk and three adapter stacks for serving.
+256-token prefill chunk and three adapter stacks for serving. Every
+kernel's HLO instruction carries the ``name=`` of its ``pallas_call``,
+which is how a profiler trace of the chip names it.
 
 The topology is described inside a module fixture, never at import time:
 only one process may load the TPU library, and a test run with several
@@ -34,6 +36,10 @@ N_PAGES = MAX_LEN // PAGE
 N_BLOCKS = SLOTS * N_PAGES
 # (d_in, d_out) of the qwen2-1.5b projections: wq/wo, wk/wv, gate/up, down
 LINEARS = [(D, D), (D, HKV * HD), (D, F), (F, D)]
+# the ``name=`` of every pallas_call under repro/kernels
+KERNEL_NAMES = ("fused_linear", "sparse_delta", "sparse_delta_batched", "delta_dval",
+                "paged_prefill_attn", "decode_attn", "paged_decode_attn", "flash_attn_fwd",
+                "fused_linear_q", "topk_select")
 
 
 @pytest.fixture(scope="module")
@@ -56,14 +62,28 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, *specs, kernels=1):
+def _kernel_names(text):
+    """The kernel name each Pallas call's HLO instruction carries (the
+    longest of ``KERNEL_NAMES`` in it: a transform may prefix it, as in
+    ``%jvp_fused_linear_.1``), or None for an unnamed call."""
+    found = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            head = line.split(" = ")[0]
+            named = [k for k in KERNEL_NAMES if k in head]
+            found.append(max(named, key=len) if named else None)
+    return found
+
+
+def _compile(fn, *specs, kernels=1, names=()):
     """Lower ``fn`` under the Pallas backend and compile it for the chip;
-    asserts the program holds ``kernels`` Pallas calls and returns its
-    optimized HLO text."""
+    asserts the program holds ``kernels`` Pallas calls, named ``names``,
+    and returns its optimized HLO text."""
     with ops.use_backend("pallas"):
         compiled = jax.jit(fn).lower(*specs).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    assert set(_kernel_names(text)) == set(names)
     return text
 
 
@@ -88,7 +108,7 @@ def test_fused_linear_train_step(one_chip, d_in, d_out):
         jax.value_and_grad(loss, argnums=(0, 3)),
         s((4, 512, d_in), BF16), s((d_in, d_out), BF16),
         s((1, d_out), jnp.int32), s((1, d_out), BF16), s((d_out,), BF16),
-        kernels=2,
+        kernels=2, names=("fused_linear", "delta_dval"),
     )
 
 
@@ -101,7 +121,7 @@ def test_delta_apply_train_step(one_chip):
     _compile(
         jax.value_and_grad(loss, argnums=(0, 2)),
         s((TRAIN_M, D), BF16), s((1, D), jnp.int32), s((1, D), BF16),
-        kernels=2,
+        kernels=2, names=("sparse_delta", "delta_dval"),
     )
 
 
@@ -118,6 +138,7 @@ def test_delta_apply_batched_serve(one_chip, rows, d_in, d_out):
         ops.delta_apply_batched,
         s((SLOTS, rows, d_in), BF16), s((TENANTS, 1, d_out), jnp.int32),
         s((TENANTS, 1, d_out), BF16), s((SLOTS,), jnp.int32),
+        names=("sparse_delta_batched",),
     )
 
 
@@ -132,7 +153,7 @@ def test_paged_decode_attention(one_chip, kv_dtype):
     ]
     if kv_dtype == "int8":
         args += [s((N_BLOCKS, HKV), jnp.float32)] * 2
-    _compile(ops.paged_decode_attention, *args)
+    _compile(ops.paged_decode_attention, *args, names=("paged_decode_attn",))
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
@@ -146,7 +167,7 @@ def test_paged_prefill_attention(one_chip, kv_dtype):
     ]
     if kv_dtype == "int8":
         args += [s((N_BLOCKS, HKV), jnp.float32)] * 2
-    _compile(ops.prefill_attention, *args)
+    _compile(ops.prefill_attention, *args, names=("paged_prefill_attn",))
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
@@ -160,7 +181,7 @@ def test_dense_decode_attention(one_chip, kv_dtype):
     ]
     if kv_dtype == "int8":
         args += [s((SLOTS, MAX_LEN // 16, HKV), jnp.float32)] * 2
-    _compile(ops.decode_attention, *args)
+    _compile(ops.decode_attention, *args, names=("decode_attn",))
 
 
 def _qtensor_specs(s, d_in, d_out, qdtype, block=64):
@@ -184,7 +205,7 @@ def test_fused_linear_q_train_step(one_chip, qdtype):
         jax.value_and_grad(loss, argnums=(0, 4)),
         s((TRAIN_M, D), BF16), data, scales,
         s((1, F), jnp.int32), s((1, F), BF16),
-        kernels=2,
+        kernels=2, names=("fused_linear_q", "delta_dval"),
     )
 
 
@@ -197,14 +218,26 @@ def test_matmul_q_serve(one_chip, qdtype):
     def fn(x, data, scales):
         return ops.matmul_q(x, QuantizedTensor(data, scales, qdtype, 64, "bfloat16"))
 
-    _compile(fn, s((SLOTS, 1, F), BF16), data, scales)
+    _compile(fn, s((SLOTS, 1, F), BF16), data, scales, names=("fused_linear_q",))
 
 
 @pytest.mark.parametrize("d_in,d_out", [(D, F), (F, D)])
 def test_topk_select(one_chip, d_in, d_out):
     """Phase-1 top-1 selection over a projection's magnitudes."""
     s = _sds(one_chip)
-    _compile(lambda w: ops.topk_select(w, 1), s((d_in, d_out), BF16))
+    _compile(lambda w: ops.topk_select(w, 1), s((d_in, d_out), BF16),
+             names=("topk_select",))
+
+
+def test_flash_attention_fwd(one_chip):
+    """The causal flash-attention forward kernel at a training shape: 4
+    sequences of 512 positions, 12 heads of 128."""
+    from repro.kernels.flash_attention import flash_attention_fwd_pallas
+
+    s = _sds(one_chip)
+    qkv = [s((4 * H, 512, HD), BF16)] * 3
+    _compile(lambda q, k, v: flash_attention_fwd_pallas(q, k, v, causal=True), *qkv,
+             names=("flash_attn_fwd",))
 
 
 def test_paged_decode_attention_tp2(topo):
@@ -227,14 +260,18 @@ def test_paged_decode_attention_tp2(topo):
             sds((N_BLOCKS, PAGE, HKV, HD), BF16, heads),
             sds((N_BLOCKS, PAGE, HKV, HD), BF16, heads),
             sds((SLOTS, N_PAGES), jnp.int32, rep), sds((SLOTS,), jnp.int32, rep),
+            names=("paged_decode_attn",),
         )
     finally:
         tp_ctx.clear_serve_mesh()
     assert "all-gather" not in text
 
 
-@pytest.mark.parametrize("tp,kernels", [(1, 8), (2, 1)], ids=["tp1", "tp2"])
-def test_mixed_serving_step(topo, tp, kernels):
+@pytest.mark.parametrize("tp,kernels,names", [
+    (1, 8, ("sparse_delta_batched", "paged_prefill_attn")),
+    (2, 1, ("paged_prefill_attn",)),
+], ids=["tp1", "tp2"])
+def test_mixed_serving_step(topo, tp, kernels, names):
     """The whole multi-tenant mixed prefill step at full depth: on one chip
     with the seven batched-delta kernels and paged prefill attention in the
     layer scan, and tensor-parallel over the ``model`` axis of a 2x2 mesh.
@@ -315,7 +352,7 @@ def test_mixed_serving_step(topo, tp, kernels):
                 placed(cache, cache_shardings(cache, mesh)),
                 vec((SLOTS,)), vec((SLOTS, N_PAGES)), vec((SLOTS, CHUNK)),
                 vec((SLOTS,)), vec((SLOTS,)),
-                kernels=kernels,
+                kernels=kernels, names=names,
             )
     finally:
         dist_ctx.restore(snap)

@@ -288,6 +288,28 @@ def test_step_seconds_ema_measured():
     assert eng.step_seconds_ema < 1.0
 
 
+def test_step_histogram_times_the_whole_step_and_ema_the_compiled_call(monkeypatch):
+    """``serve_step_seconds{kind}`` observes the whole ``step()`` call,
+    the interval of its ``serve.step`` span, admission and the gauges
+    included; the admission gate's EMA keeps timing the compiled call."""
+    clock = FakeClock()
+    eng = _engine(metrics=True, clock=clock)
+    admit, gauges = eng._admit, eng._update_gauges
+    monkeypatch.setattr(eng, "_admit", lambda: (clock.advance(3.0), admit())[1])
+    monkeypatch.setattr(eng, "_update_gauges", lambda: (clock.advance(0.5), gauges())[1])
+    real_chunk, real_decode = eng._chunk_step, eng._decode_step
+    monkeypatch.setattr(eng, "_chunk_step", lambda k: (clock.advance(0.25), real_chunk(k))[1])
+    monkeypatch.setattr(eng, "_decode_step", lambda k: (clock.advance(0.25), real_decode(k))[1])
+    eng.submit([1, 5, 9], max_new=4)
+    steps = 0
+    while eng.step():
+        steps += 1
+    hist = eng.metrics.get("serve_step_seconds")
+    assert hist.labels("mixed").count + hist.labels("decode").count == steps
+    assert hist.labels("mixed").sum + hist.labels("decode").sum == pytest.approx(3.75 * steps)
+    assert eng.step_seconds_ema == pytest.approx(0.25)
+
+
 # --------------------------------------------------------- graceful drain
 
 

@@ -86,3 +86,36 @@ def test_merged_params_match_adapter_forward():
     np.testing.assert_allclose(
         np.asarray(lg_ad, np.float32), np.asarray(lg_merged, np.float32), atol=0.15
     )  # bf16 rounding on merge
+
+
+def test_one_fetch_per_step_and_history_unchanged(monkeypatch):
+    """Each step's metrics come to the host in ONE ``jax.device_get`` of
+    the whole dict (plus one read of the step counter per ``run`` call);
+    no metric is fetched on its own, and the history holds the same
+    numbers as fetching each metric with ``float``."""
+    from jax._src.array import ArrayImpl
+
+    cfg, m, params, peft, tcfg = _setup()
+    batches = [peek_batch("lm", cfg.vocab_size, 4, 16, seed=i) for i in range(3)]
+    ref = Trainer(m, peft, tcfg, params)
+    want = []
+    for b in batches:
+        ref.state, metrics = ref._step_fn(
+            ref.params, ref.aux, ref.state, {k: jnp.asarray(v) for k, v in b.items()}
+        )
+        want.append({k: float(v) for k, v in metrics.items()})
+
+    tr = Trainer(m, peft, tcfg, params)
+    calls = []
+    real = jax.device_get
+    monkeypatch.setattr(jax, "device_get", lambda x: (calls.append(1), real(x))[1])
+
+    def refuse(self, *a):
+        raise AssertionError("a device value was fetched on its own")
+
+    for conv in ("__float__", "__int__", "__bool__", "__index__"):
+        monkeypatch.setattr(ArrayImpl, conv, refuse)
+    hist = tr.run(iter(batches), steps=3)
+    assert len(calls) == 1 + 3
+    assert [{k: h[k] for k in w} for h, w in zip(hist, want)] == want
+    assert [h["step"] for h in hist] == [0, 1, 2]
