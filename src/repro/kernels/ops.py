@@ -453,9 +453,11 @@ def prefill_attention(
     (B, n_pages) block table; per-slot ``q_offset`` anchors the chunk's
     intra-causal mask and ``kv_valid_len`` is the post-write cache
     frontier. jnp backend: gather-then-masked-softmax oracle; Pallas
-    backends: the scalar-prefetch page-sweep kernel (physical pages DMA
-    straight from the pool, online softmax in VMEM). With ``k_scale``/
-    ``v_scale`` (N, Hkv) the pool is int8, dequantized per page tile.
+    backends: the ragged kernel, which sweeps each slot's real query rows
+    against its live pages (physical pages DMA straight from the pool,
+    online softmax in VMEM). Rows past a slot's ``q_len`` are zero on
+    both. With ``k_scale``/``v_scale`` (N, Hkv) the pool is int8,
+    dequantized per page tile.
     """
     if get_backend() == "jnp":
         if k_scale is not None:

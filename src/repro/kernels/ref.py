@@ -105,7 +105,8 @@ def prefill_attention_ref(
     scalar or (B,). Query ``i`` (logical position ``q_offset[b] + i``)
     sees column ``c`` iff ``c <= q_offset[b] + i`` and
     ``c < kv_valid_len[b]`` — intra-chunk causality plus the per-slot
-    cache frontier. f32 softmax; fully-masked rows return zeros.
+    cache frontier. f32 softmax; rows of pad queries
+    ``i >= kv_valid_len[b] - q_offset[b]`` return zeros.
     """
     b, c, h, hd = q.shape
     skv, hkv = k.shape[1], k.shape[2]
@@ -116,7 +117,8 @@ def prefill_attention_ref(
     vl = jnp.broadcast_to(jnp.asarray(kv_valid_len, jnp.int32).reshape(-1), (b,))
     col = jnp.arange(skv)[None, None, :]
     qpos = qoff[:, None, None] + jnp.arange(c)[None, :, None]
-    mask = (col <= qpos) & (col < vl[:, None, None])  # (B, C, Skv)
+    real = qpos < vl[:, None, None]                   # (B, C, 1)
+    mask = (col <= qpos) & (col < vl[:, None, None]) & real  # (B, C, Skv)
     mask = mask[:, None, None]                        # (B, 1, 1, C, Skv)
     s = jnp.where(mask, s, -1e30)
     p = jnp.where(mask, jax.nn.softmax(s, axis=-1), 0.0)

@@ -1469,9 +1469,14 @@ class ServeEngine:
             plan = self.scheduler.chunk_plan(self.prefill_chunk, self.kv.pos_host)
             stacked = self._stacked()
             # the buffer's rows against the rows that hold a real token
-            sp.set_metadata(
-                rows=plan["tokens"].size, real_rows=int(plan["q_len"].sum())
-            )
+            counts = {"rows": plan["tokens"].size, "real_rows": int(plan["q_len"].sum())}
+            if self.paged:
+                # pages the prefill attention kernel sweeps (each slot with
+                # a query, up to its frontier) against the whole table
+                vl = (plan["q_offset"] + plan["q_len"])[plan["q_len"] > 0]
+                counts["kv_pages"] = int((-(-vl // self.kv.page_size)).sum())
+                counts["kv_pages_grid"] = self.kv.table.size
+            sp.set_metadata(**counts)
         spec = self.draft_kv is not None  # ngram prefills like plain
         with span(spans.SERVE_PUT):
             lead = [self.params]

@@ -4,9 +4,12 @@ The kernel serves the mixed prefill+decode serving step (DESIGN §11):
 per-slot query chunks against the shared block pool, block tables and
 per-slot (q_offset, kv_valid_len) as scalar prefetch, intra-chunk causal
 masking on top of the cache frontier. The sweeps cover GQA group sizes,
-ragged offsets/lengths (decode slots as degenerate one-token chunks),
-shared and sentinel table entries, and bf16 inputs; the oracle itself is
-pinned against plain dense causal attention on a contiguous cache.
+ragged offsets/lengths (idle slots, decode slots as degenerate one-token
+chunks, frontiers on page and KV-block boundaries and at the table's
+end, q-tiles whose causal bound ends inside a KV block), shared and
+sentinel table entries, and bf16 inputs; pad rows come out as zeros.
+The oracle itself is pinned against plain dense causal attention on a
+contiguous cache.
 """
 
 import jax
@@ -19,26 +22,46 @@ from repro.kernels.prefill_attention import paged_prefill_attention_pallas
 from repro.models.attention import dense_attention
 
 
-def _pool_case(rng, b, c, h, hkv, hd, nblk, page, npages, dtype):
-    q = jnp.asarray(rng.normal(size=(b, c, h, hd)), dtype)
-    kp = jnp.asarray(rng.normal(size=(nblk, page, hkv, hd)), dtype)
-    vp = jnp.asarray(rng.normal(size=(nblk, page, hkv, hd)), dtype)
-    table = jnp.asarray(rng.integers(0, nblk, size=(b, npages)), jnp.int32)
-    qoff = jnp.asarray(
-        rng.integers(0, page * npages - c + 1, size=(b,)), jnp.int32
-    )
-    vl = qoff + jnp.asarray(rng.integers(1, c + 1, size=(b,)), jnp.int32)
-    return q, kp, vp, table, qoff, vl
+# (q_offset, q_len) of the ragged layout's fixed slots, in 12 pages of 16
+# with a 32-query chunk: an idle slot; a stalled prefill (cache, no query);
+# decode slots whose frontier falls on a page boundary inside a KV block,
+# on a block boundary and mid-block; a chunk whose frontier is the
+# table's last page; a chunk whose q-tiles end inside a KV block. A last
+# slot is drawn at random.
+RAGGED_SLOTS = [(0, 0), (40, 0), (47, 1), (127, 1), (150, 1), (160, 32), (120, 25)]
+PAGE, N_PAGES, CHUNK = 16, 12, 32
 
 
-@pytest.mark.parametrize("g", [1, 2, 4])
+def ragged_case(rng, h, hkv, hd, nblk, dtype):
+    """q, pools, table and per-slot (q_offset, kv_valid_len) of the ragged
+    layout; table entries past a slot's frontier are the sentinel."""
+    qoff = [o for o, _ in RAGGED_SLOTS] + [int(rng.integers(0, PAGE * N_PAGES - CHUNK + 1))]
+    vl = [o + n for o, n in RAGGED_SLOTS] + [qoff[-1] + int(rng.integers(1, CHUNK + 1))]
+    b = len(qoff)
+    q = jnp.asarray(rng.normal(size=(b, CHUNK, h, hd)), dtype)
+    kp = jnp.asarray(rng.normal(size=(nblk, PAGE, hkv, hd)), dtype)
+    vp = jnp.asarray(rng.normal(size=(nblk, PAGE, hkv, hd)), dtype)
+    table = rng.integers(0, nblk, size=(b, N_PAGES))
+    for i, v in enumerate(vl):
+        table[i, -(-v // PAGE):] = nblk
+    return (q, kp, vp, jnp.asarray(table, jnp.int32), jnp.asarray(qoff, jnp.int32),
+            jnp.asarray(vl, jnp.int32))
+
+
+def assert_pad_rows_zero(out, qoff, vl):
+    """Every row is finite; rows of queries ``i >= q_len`` (all of an idle
+    or stalled slot's) are zero."""
+    out = np.asarray(out, np.float32)
+    assert np.isfinite(out).all()
+    pad = np.arange(out.shape[1])[None, :] >= (np.asarray(vl) - np.asarray(qoff))[:, None]
+    assert not out[pad].any()
+
+
+@pytest.mark.parametrize("g", [1, 2, 4, 6])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_prefill_kernel_matches_ref(g, dtype):
     rng = np.random.default_rng(7 + g)
-    hkv, hd, page, npages, nblk = 2, 16, 4, 6, 14
-    q, kp, vp, table, qoff, vl = _pool_case(
-        rng, 3, 8, g * hkv, hkv, hd, nblk, page, npages, dtype
-    )
+    q, kp, vp, table, qoff, vl = ragged_case(rng, g * 2, 2, 16, 40, dtype)
     want = ref.paged_prefill_attention_ref(q, kp, vp, table, qoff, vl)
     got = paged_prefill_attention_pallas(
         q, kp, vp, table, qoff, vl, interpret=True
@@ -48,6 +71,7 @@ def test_paged_prefill_kernel_matches_ref(g, dtype):
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         atol=tol, rtol=tol,
     )
+    assert_pad_rows_zero(got, qoff, vl)
 
 
 def test_paged_prefill_kernel_shared_and_sentinel_pages():

@@ -214,16 +214,36 @@ def test_paged_decode_kernel_q_matches_ref(case):
     )
 
 
-@pytest.mark.parametrize("case", PAGED_CASES[:2])
+# (q_offset, q_len) of a ragged mixed step, 32-query chunks in 12 pages of
+# 16: idle, stalled, decode on a page boundary, on a KV-block boundary
+# and mid-block, a chunk to the table's last page, q-tiles ending inside
+# a KV block
+RAGGED_SLOTS = [(0, 0), (40, 0), (47, 1), (127, 1), (150, 1), (160, 32), (120, 25)]
+
+
+@pytest.mark.parametrize(
+    "case", PAGED_CASES[:2] + [(len(RAGGED_SLOTS), 96, 16, 12, 12, 2, 16)]
+)
 def test_paged_prefill_kernel_q_matches_ref(case):
+    """Random chunks, and (at G = 6) the ragged layout, whose pad rows
+    and idle slots must come out as finite zeros."""
     b, nblk, page, npages, h, hkv, hd = case
     _, kc, vc, ks, vs, table, _ = _paged_case(*case)
-    c = 8
+    ragged = b == len(RAGGED_SLOTS)
+    c = 32 if ragged else 8
     q = jnp.asarray(RNG.normal(size=(b, c, h, hd)), jnp.float32)
-    qoff = jnp.asarray(
-        RNG.integers(0, page * npages - c + 1, size=(b,)), jnp.int32
-    )
-    vl = qoff + jnp.asarray(RNG.integers(1, c + 1, size=(b,)), jnp.int32)
+    if ragged:
+        qoff = jnp.asarray([o for o, _ in RAGGED_SLOTS], jnp.int32)
+        vl = qoff + jnp.asarray([n for _, n in RAGGED_SLOTS], jnp.int32)
+        table = np.array(table)
+        for i, v in enumerate(np.asarray(vl)):
+            table[i, -(-int(v) // page):] = nblk
+        table = jnp.asarray(table)
+    else:
+        qoff = jnp.asarray(
+            RNG.integers(0, page * npages - c + 1, size=(b,)), jnp.int32
+        )
+        vl = qoff + jnp.asarray(RNG.integers(1, c + 1, size=(b,)), jnp.int32)
     want = ref.paged_prefill_attention_q_ref(
         q, kc, vc, ks, vs, table, qoff, vl
     )
@@ -233,6 +253,10 @@ def test_paged_prefill_kernel_q_matches_ref(case):
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5
     )
+    out = np.asarray(got)
+    assert np.isfinite(out).all()
+    pad = np.arange(c)[None, :] >= np.asarray(vl - qoff)[:, None]
+    assert not out[pad].any()
 
 
 def test_quantized_attention_close_to_fp32():

@@ -19,6 +19,10 @@ workers must collect the same tests in each.
 
 from __future__ import annotations
 
+import importlib.util
+import pathlib
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from repro.kernels import ops
 from repro.quant.qtensor import QuantizedTensor
 
+ROOT = pathlib.Path(__file__).resolve().parents[2]
 D, F, H, HKV, HD = 1536, 8960, 12, 2, 128
 BF16 = jnp.bfloat16
 TRAIN_M = 4 * 512
@@ -168,6 +173,44 @@ def test_paged_prefill_attention(one_chip, kv_dtype):
     if kv_dtype == "int8":
         args += [s((N_BLOCKS, HKV), jnp.float32)] * 2
     _compile(ops.prefill_attention, *args, names=("paged_prefill_attn",))
+
+
+def test_paged_prefill_attention_signature(one_chip):
+    """At the serving benchmark's shapes (32 slots, chunk 256, 12/2 heads,
+    160 pages of 16) the kernel's call keeps the six operands by which
+    ``bench/metrics/paged_prefill_attn_roofline.py`` finds it in a trace:
+    block table s32[N], chunk offsets and frontiers s32[B], q
+    bf16[B,KV,C*G,hd], and the K and V pools bf16[N,page,KV*hd]."""
+    if str(ROOT) not in sys.path:
+        sys.path.insert(0, str(ROOT))
+    from jax._src.lib import xla_client
+
+    from bench.trace import Event, kernel_call
+
+    spec = importlib.util.spec_from_file_location(
+        "prefill_roofline", ROOT / "bench" / "metrics" / "paged_prefill_attn_roofline.py")
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    slots, pages = 32, 160
+    n = slots * pages
+    s = _sds(one_chip)
+    args = (s((slots, CHUNK, H, HD), BF16),
+            s((n, PAGE, HKV, HD), BF16), s((n, PAGE, HKV, HD), BF16),
+            s((slots, pages), jnp.int32), s((slots,), jnp.int32), s((slots,), jnp.int32))
+    with ops.use_backend("pallas"):
+        compiled = jax.jit(ops.prefill_attention).lower(*args).compile()
+    # the instruction as a trace's event names it, operand shapes inline
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    (line,) = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert line.split(" = ")[0].strip().startswith("paged_prefill_attn")
+    out, args = kernel_call(Event(line.strip(), 0.0, 0.0))
+    assert args == [("s32", (n,)), ("s32", (slots,)), ("s32", (slots,)),
+                    ("bf16", (slots, HKV, CHUNK * H // HKV, HD)),
+                    ("bf16", (n, PAGE, HKV * HD)), ("bf16", (n, PAGE, HKV * HD))]
+    assert out == ("bf16", (slots, HKV, CHUNK * H // HKV, HD))
+    assert reader.is_call(out, args)
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])

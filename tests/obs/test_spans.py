@@ -65,7 +65,8 @@ def test_serve_step_phases_nest_in_the_step(tmp_path, monkeypatch, kind):
     """A paged engine's mixed step, decode megastep and n-gram
     speculative megastep each show every phase inside ``serve.step``;
     the mixed step's plan counts the buffer's rows and the real ones
-    (Σ q_len of the plan it ran), and the replay counts what it emitted."""
+    (Σ q_len of the plan it ran), the pages the prefill attention kernel
+    sweeps and the table's, and the replay counts what it emitted."""
     _, m, params = _model()
     eng = ServeEngine(m, params, slots=SLOTS, max_len=64, eos_id=1 << 20, paged=True,
                       decode_chunk=2, prefill_chunk=CHUNK,
@@ -90,12 +91,32 @@ def test_serve_step_phases_nest_in_the_step(tmp_path, monkeypatch, kind):
     assert stats[spans.SERVE_EMIT] == {"emitted": emitted}
     if kind == "mixed":
         (plan,) = plans
-        assert stats[spans.SERVE_PLAN] == {"rows": SLOTS * CHUNK,
-                                           "real_rows": int(plan["q_len"].sum())}
+        vl = plan["q_offset"] + plan["q_len"]
+        assert stats[spans.SERVE_PLAN] == {
+            "rows": SLOTS * CHUNK, "real_rows": int(plan["q_len"].sum()),
+            "kv_pages": int(sum(-(-v // 16) for v, n in zip(vl, plan["q_len"]) if n)),
+            "kv_pages_grid": SLOTS * 64 // 16,
+        }
         assert 0 < plan["q_len"].sum() <= CHUNK + SLOTS
     else:
         assert not plans and stats[spans.SERVE_PLAN] == {}
         assert emitted > 0
+
+
+def test_mixed_step_plan_counts_kv_pages(tmp_path):
+    """``kv_pages`` on the mixed step's plan, by hand: in 4-token pages the
+    second step's chunks end at 12 (slot 0, 4 more prompt tokens) and at 4
+    (slot 1, its whole prompt), 3 + 1 pages; the idle slot 2 sweeps none.
+    ``kv_pages_grid`` is the table: 3 slots × 16 pages."""
+    _, m, params = _model()
+    eng = ServeEngine(m, params, slots=SLOTS, max_len=64, eos_id=1 << 20, paged=True,
+                      page_size=4, prefill_chunk=CHUNK)
+    eng.submit([1, 5, 9, 2] * 3, max_new=4)
+    eng.submit([1, 6, 9, 2], max_new=4)
+    eng.step()  # slot 0 takes the budget's 8 prompt tokens; slot 1 waits
+    events = _profiled(tmp_path, eng.step)
+    (plan,) = [e[3] for e in events if e[0] == spans.SERVE_PLAN]
+    assert plan == {"rows": 24, "real_rows": 8, "kv_pages": 4, "kv_pages_grid": 48}
 
 
 def test_train_step_phases_nest_in_the_step(tmp_path):
