@@ -63,8 +63,12 @@ def _combine_group(out_e, route, tg, dtype):
     return jnp.zeros((tg, dm), dtype).at[tok_of].add(contrib)
 
 
-def moe_ffn(cfg, p, a, x, *, groups: int = 32):
+def moe_ffn(cfg, p, a, x, *, groups: int = 32, cap: int | None = None):
     """x (B,S,D) -> (out (B,S,D), aux_loss scalar).
+
+    ``cap`` sets each group's per-expert capacity (default
+    :func:`capacity` of the group's tokens); ``cap`` = tokens per group
+    drops none.
 
     Group-local routing (§Perf iteration 5): tokens are split into
     ``groups`` independent routing groups (aligned with data shards), so
@@ -83,7 +87,7 @@ def moe_ffn(cfg, p, a, x, *, groups: int = 32):
             g = 1
             break
     tg = t // g
-    c = capacity(cfg, tg)
+    c = capacity(cfg, tg) if cap is None else cap
     xt = x.reshape(g, tg, dm)
 
     logits = jnp.einsum("gtd,de->gte", xt, p["router"]["w"]).astype(jnp.float32)
@@ -132,7 +136,9 @@ def _dispatch_adapter_ids(a, route, b, s, g, e, c):
     if d0 is None:
         return None
     tg = b * s // g
-    aid_t = jnp.broadcast_to(d0.aid[:, None], (b, s)).reshape(g, tg)
+    # (B,) per sequence, or (1, T) per row of the mixed step's flat buffer
+    aid = d0.aid.reshape(d0.aid.shape + (1,) * (2 - d0.aid.ndim))
+    aid_t = jnp.broadcast_to(aid, (b, s)).reshape(g, tg)
 
     def one(aid_g, tok_of, dest):
         buf = jnp.zeros((e * c,), jnp.int32)

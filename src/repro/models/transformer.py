@@ -8,6 +8,8 @@ cache through scan ``xs``/``ys``.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
@@ -106,9 +108,9 @@ def init_params(cfg, rng):
 # ------------------------------------------------------------------- block
 
 
-def _mlp(cfg, p, a, x):
+def _mlp(cfg, p, a, x, **moe_kw):
     if cfg.num_experts:
-        return moe_lib.moe_ffn(cfg, p, a, x)
+        return moe_lib.moe_ffn(cfg, p, a, x, **moe_kw)
     h = jax.nn.silu(alinear(p, a, "wgate", x)) * alinear(p, a, "wup", x)
     h = constrain_inner(h)  # Megatron TP layout for the hidden
     return alinear(p, a, "wdown", h), jnp.float32(0.0)
@@ -401,10 +403,59 @@ def prefill(cfg, params, adapters, batch):
     return logits, {"k": ck, "v": cv}
 
 
+class _Rows(NamedTuple):
+    """Row maps between a (B, C) chunk and its flat layout of T rows.
+
+    Slot s's ``q_len[s]`` real tokens sit at flat rows ``start[s]`` …
+    ``start[s] + q_len[s] - 1``, slot after slot; rows past Σ ``q_len`` are
+    pads, whose grid cell ``B·C`` lies out of range, so a gather through
+    it reads zeros."""
+
+    start: jax.Array  # (B,) first flat row of each slot
+    slot: jax.Array  # (T,) slot of each flat row (B - 1 for pads)
+    pos: jax.Array  # (T,) chunk index of each flat row (0 for pads)
+    grid: jax.Array  # (T,) grid cell s·C + i of each flat row
+
+
+def _chunk_rows(q_len, c, rows):
+    """Flat row maps for a (B, c) chunk computed on ``rows`` rows, or None
+    where ``rows`` covers the whole grid (the identity layout)."""
+    b = q_len.shape[0]
+    if rows is None or rows >= b * c:
+        return None
+    end = jnp.cumsum(q_len)
+    start = end - q_len
+    r = jnp.arange(rows)
+    slot = jnp.sum(r[:, None] >= end[None, :], axis=1)  # B past Σ q_len
+    real = slot < b
+    slot = jnp.minimum(slot, b - 1)
+    pos = jnp.where(real, r - start[slot], 0)
+    return _Rows(start, slot, pos, jnp.where(real, slot * c + pos, b * c))
+
+
+def _flat_adapters(a_blocks, slot):
+    """Block adapters for flat rows: a tenant delta's per-slot ``aid``
+    (L, B) becomes a per-row (L, 1, T), each row taking its slot's id."""
+
+    def one(d):
+        if isinstance(d, BatchedDelta):
+            return d._replace(aid=d.aid[:, slot][:, None, :])
+        return d
+
+    return jax.tree.map(one, a_blocks, is_leaf=lambda d: isinstance(d, BatchedDelta))
+
+
 def _chunk_forward(cfg, params, adapters, cache, batch):
     """Shared body of :func:`prefill_chunk` / :func:`verify_chunk`: run a
-    (B, C) token chunk through the layer stack against a live KV cache,
-    returning the full (B, C, D) hidden states and the updated cache."""
+    (B, C) token chunk through the layer stack against a live KV cache.
+
+    With ``batch["rows"]`` = T < B·C the row-wise work (embedding, norms,
+    QKV with RoPE, ``wo``, the MLP, whose MoE routing drops no token) runs
+    on a flat (1, T, D) buffer of the chunk's real tokens (DESIGN §11); only the cache write and the
+    attention kernel see the (B, C) grid, sliced from the flat rows, and
+    the attention output is gathered back. Returns the hidden states —
+    (1, T, D) flat, else (B, C, D) — the updated cache and the row maps
+    (None for the grid)."""
     dt = compute_dtype(cfg)
     tokens = batch["tokens"]
     q_offset = batch["q_offset"]
@@ -412,34 +463,63 @@ def _chunk_forward(cfg, params, adapters, cache, batch):
     table = batch.get("block_table")
     wtable = batch.get("write_table")
     b, c = tokens.shape
-    h = jnp.take(params["embed"]["w"], tokens, axis=0).astype(dt)
-    positions = q_offset[:, None] + jnp.arange(c)[None, :]
-    vl = q_offset + q_len
+    rm = _chunk_rows(q_len, c, batch.get("rows"))
     blocks, a_blocks = _split_blocks(params, adapters)
+    moe_kw = {}
+    if rm is None:
+        positions = q_offset[:, None] + jnp.arange(c)[None, :]
+
+        def to_grid(x):
+            return x
+
+        def to_rows(x):
+            return x
+    else:
+        tokens = jnp.take(tokens.reshape(-1), rm.grid, mode="fill", fill_value=0)[None]
+        positions = (q_offset[rm.slot] + rm.pos)[None]
+        a_blocks = _flat_adapters(a_blocks, rm.slot)
+        # one routing group whose experts each hold every row: no real
+        # token is dropped, whichever slots share the step (DESIGN §11)
+        moe_kw = {"groups": 1, "cap": rm.slot.shape[0]}
+
+        def to_grid(x):
+            # slot s's chunk is the C rows from start[s], one contiguous
+            # slice each; cells past q_len hold the next rows, which the
+            # cache write drops and the attention output leaves unread
+            x = jnp.pad(x[0], ((0, c),) + ((0, 0),) * (x.ndim - 2))
+            return jax.vmap(lambda s: jax.lax.dynamic_slice_in_dim(x, s, c))(rm.start)
+
+        def to_rows(x):
+            x = x.reshape(b * c, *x.shape[2:])
+            return jnp.take(x, rm.grid, axis=0, mode="fill", fill_value=0)[None]
+
+    h = jnp.take(params["embed"]["w"], tokens, axis=0).astype(dt)
+    vl = q_offset + q_len
 
     def body(hh, xs):
-        p, a, c = xs
+        p, a, kv = xs
         x = rms_norm(hh, p["attn_norm"], cfg.norm_eps)
-        q, k, v = _qkv(cfg, p, a, x, positions, None)
+        q, k, v = (to_grid(t) for t in _qkv(cfg, p, a, x, positions, None))
         if table is None:
-            c = _write_chunk(c, k, v, None, q_offset, q_len)
+            kv = _write_chunk(kv, k, v, None, q_offset, q_len)
             o = chunk_attention(
-                q, c["k"], c["v"], cfg, q_offset=q_offset, kv_valid_len=vl,
-                k_scale=c.get("k_scale"), v_scale=c.get("v_scale"),
+                q, kv["k"], kv["v"], cfg, q_offset=q_offset, kv_valid_len=vl,
+                k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"),
             )
         else:
-            c = _write_chunk(c, k, v, wtable, q_offset, q_len)
+            kv = _write_chunk(kv, k, v, wtable, q_offset, q_len)
             o = paged_prefill_attention(
-                q, c["k"], c["v"], table, cfg, q_offset=q_offset, kv_valid_len=vl,
-                k_scale=c.get("k_scale"), v_scale=c.get("v_scale"),
+                q, kv["k"], kv["v"], table, cfg, q_offset=q_offset, kv_valid_len=vl,
+                k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"),
             )
+        o = to_rows(o)
         hh = hh + alinear(p, a, "wo", o.reshape(*o.shape[:2], -1))
         x = rms_norm(hh, p["mlp_norm"], cfg.norm_eps)
-        y, _ = _mlp(cfg, p, a, x)
-        return hh + y, c
+        y, _ = _mlp(cfg, p, a, x, **moe_kw)
+        return hh + y, kv
 
     h, cache = jax.lax.scan(body, h, (blocks, a_blocks, cache))
-    return h, cache
+    return h, cache, rm
 
 
 def prefill_chunk(cfg, params, adapters, cache, batch):
@@ -459,10 +539,16 @@ def prefill_chunk(cfg, params, adapters, cache, batch):
 
     batch: {"tokens": (B, C) int32, "q_offset": (B,) int32,
     "q_len": (B,) int32, "last_idx": (B,) int32,
-    ["block_table"/"write_table": (B, n_pages) int32 — paged serving]}.
+    ["block_table"/"write_table": (B, n_pages) int32 — paged serving],
+    ["rows": int — static row count T ≥ Σ ``q_len``: the dense work runs
+    on T flat rows instead of B·C (the caller bounds Σ ``q_len``)]}.
     """
-    h, cache = _chunk_forward(cfg, params, adapters, cache, batch)
-    hs = jnp.take_along_axis(h, batch["last_idx"][:, None, None], axis=1)
+    h, cache, rm = _chunk_forward(cfg, params, adapters, cache, batch)
+    last = batch["last_idx"]
+    if rm is None:
+        hs = jnp.take_along_axis(h, last[:, None, None], axis=1)
+    else:
+        hs = jnp.take(h[0], rm.start + last, axis=0, mode="clip")[:, None]
     hs = rms_norm(hs, params["final_norm"], cfg.norm_eps)
     logits = _head_logits(cfg, params, adapters, hs)[:, 0]
     return logits, cache
@@ -480,7 +566,7 @@ def verify_chunk(cfg, params, adapters, cache, batch):
     slot's ``q_len`` are garbage the caller must mask (their writes
     already dropped in-graph).
     """
-    h, cache = _chunk_forward(cfg, params, adapters, cache, batch)
+    h, cache, _ = _chunk_forward(cfg, params, adapters, cache, batch)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     return _head_logits(cfg, params, adapters, h), cache
 

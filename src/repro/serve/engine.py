@@ -104,6 +104,7 @@ from repro.serve.scheduler import (
     RateLimitedError,
     Request,
     Scheduler,
+    chunk_rows,
 )
 
 __all__ = [
@@ -340,6 +341,9 @@ class ServeEngine:
 
         L = model.cfg.num_layers
         eos, mlen, chunk = eos_id, max_len, decode_chunk
+        # the mixed step's dense work runs on this many flat token rows,
+        # the most a chunk plan can fill, not on the (slots, C) buffer
+        self.chunk_rows = rows = chunk_rows(slots, self.prefill_chunk)
 
         def batched_adapters(aidx, aval, aid):
             # blocks leaves ride the layer scan: their aid copy carries a
@@ -368,7 +372,7 @@ class ServeEngine:
             every role (decode +1, prefill +take, idle frozen).
             """
             batch = {"tokens": tokens, "q_offset": q_offset,
-                     "q_len": q_len, "last_idx": last_idx}
+                     "q_len": q_len, "last_idx": last_idx, "rows": rows}
             if table is not None:
                 batch["block_table"] = table
                 batch["write_table"] = wtable
@@ -476,13 +480,13 @@ class ServeEngine:
             drafting for exactly this reason — see ``_try_place``).
             """
             batch = {"tokens": tokens, "q_offset": q_offset,
-                     "q_len": q_len, "last_idx": last_idx}
+                     "q_len": q_len, "last_idx": last_idx, "rows": rows}
             if table is not None:
                 batch["block_table"] = table
                 batch["write_table"] = wtable
             logits, cache = model.prefill_chunk(p, adapters, cache, batch)
             dbatch = {"tokens": tokens, "q_offset": q_offset,
-                      "q_len": q_len, "last_idx": last_idx}
+                      "q_len": q_len, "last_idx": last_idx, "rows": rows}
             _, dcache = model.prefill_chunk(dp, None, dcache, dbatch)
             toks = self.sampler(logits, temps, key)
             return cache, dcache, q_offset + q_len, toks
@@ -1468,8 +1472,8 @@ class ServeEngine:
         with span(spans.SERVE_PLAN) as sp:
             plan = self.scheduler.chunk_plan(self.prefill_chunk, self.kv.pos_host)
             stacked = self._stacked()
-            # the buffer's rows against the rows that hold a real token
-            counts = {"rows": plan["tokens"].size, "real_rows": int(plan["q_len"].sum())}
+            # the rows the device computes against those holding a token
+            counts = {"rows": self.chunk_rows, "real_rows": int(plan["q_len"].sum())}
             if self.paged:
                 # pages the prefill attention kernel sweeps (each slot with
                 # a query, up to its frontier) against the whole table

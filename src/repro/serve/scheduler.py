@@ -66,6 +66,15 @@ POLICIES = ("fifo", "drr")
 TERMINAL_REASONS = ("eos", "max_new", "cache_full", "cancelled", "deadline")
 
 
+def chunk_rows(slots: int, budget: int) -> int:
+    """Token rows the mixed step computes: :meth:`Scheduler.chunk_plan`
+    gives prompts at most ``budget`` tokens and every other slot at most
+    one, so a plan holds at most ``budget + slots - 1`` real tokens —
+    rounded up to a whole sublane tile of 8, capped at the full
+    ``slots × budget`` buffer (DESIGN §11)."""
+    return min(slots * budget, -(-(budget + slots - 1) // 8) * 8)
+
+
 class QueueFullError(RuntimeError):
     """Bounded admission queue is at ``queue_limit``: shed the request
     (HTTP 503 + Retry-After at the front end) instead of queueing it."""
@@ -442,6 +451,9 @@ class Scheduler:
         slots whose basis completes within the chunk. Stalled prefill
         slots (budget exhausted) and empty slots ride along as ``q_len =
         0`` no-ops whose position freezes at ``q_offset``.
+
+        The step computes :func:`chunk_rows` flat token rows; a plan with
+        more real tokens raises rather than lose any.
         """
         n = self.slots
         plan = {
@@ -480,6 +492,12 @@ class Scheduler:
                 plan["q_offset"][s] = int(kv_pos[s])
                 plan["q_len"][s] = 1
                 plan["emit"][s] = True
+        rows = chunk_rows(n, budget)
+        if plan["q_len"].sum() > rows:
+            raise RuntimeError(
+                f"mixed step holds {plan['q_len'].sum()} tokens, past its "
+                f"{rows} rows"
+            )
         return plan
 
     def slot_arrays(self) -> dict[str, np.ndarray]:

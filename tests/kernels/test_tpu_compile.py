@@ -315,9 +315,10 @@ def test_paged_decode_attention_tp2(topo):
     (2, 1, ("paged_prefill_attn",)),
 ], ids=["tp1", "tp2"])
 def test_mixed_serving_step(topo, tp, kernels, names):
-    """The whole multi-tenant mixed prefill step at full depth: on one chip
-    with the seven batched-delta kernels and paged prefill attention in the
-    layer scan, and tensor-parallel over the ``model`` axis of a 2x2 mesh.
+    """The whole multi-tenant mixed prefill step at full depth, on the flat
+    token rows the engine computes: on one chip with the seven batched-delta
+    kernels and paged prefill attention in the layer scan, and
+    tensor-parallel over the ``model`` axis of a 2x2 mesh.
     Mosaic kernels cannot be partitioned automatically, so under TP each
     one must sit in a shard_map (interpret mode on virtual CPU devices
     cannot show this); there only the attention kernel remains."""
@@ -333,6 +334,7 @@ def test_mixed_serving_step(topo, tp, kernels, names):
         param_shardings,
     )
     from repro.models import get_model
+    from repro.serve.scheduler import chunk_rows
 
     devices = np.array(topo.devices[:4 if tp > 1 else 1]).reshape(-1, tp)
     mesh = Mesh(devices, ("data", "model"), axis_types=(AxisType.Auto,) * 2)
@@ -376,6 +378,7 @@ def test_mixed_serving_step(topo, tp, kernels, names):
         return model.prefill_chunk(params, adapters, cache, {
             "tokens": tokens, "q_offset": q_offset, "q_len": q_len,
             "last_idx": q_len - 1, "block_table": table, "write_table": table,
+            "rows": chunk_rows(SLOTS, CHUNK),
         })
 
     def vec(shape):

@@ -9,29 +9,38 @@ per-prompt-length recompiles, ONE device→host transfer per mixed step,
 decode streams that keep emitting while a long prompt prefills,
 mid-prefill preemption that resumes exactly, prefix sharing that spans
 multiple chunks (with the sharer's chunk walk skipping resident pages),
-and the paged prefill-attention kernel wired e2e under interpret mode.
+the paged prefill-attention kernel wired e2e under interpret mode, and
+the flat row layout of the mixed step's dense work: a plan never holds
+more real tokens than the rows the step computes, and the flat rows
+reproduce the (B, C) grid's logits and cache writes.
 """
 
+import random
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config, reduced
 from repro.core.adapt import init_adapters
+from repro.core.delta import BatchedDelta
 from repro.kernels import ops
 from repro.models import get_model
 from repro.serve import AdapterStore, ServeEngine
+from repro.serve import scheduler
+from repro.serve.scheduler import Scheduler, chunk_rows
 
 _NO_EOS = 1 << 20
 _CACHE = {}
 
 
-def _model():
-    if "m" not in _CACHE:
-        cfg = reduced(get_config("qwen2-1.5b")).replace(dtype="float32")
+def _model(arch="qwen2-1.5b"):
+    if arch not in _CACHE:
+        cfg = reduced(get_config(arch)).replace(dtype="float32")
         m = get_model(cfg)
-        _CACHE["m"] = (cfg, m, m.init(jax.random.PRNGKey(0)))
-    return _CACHE["m"]
+        _CACHE[arch] = (cfg, m, m.init(jax.random.PRNGKey(0)))
+    return _CACHE[arch]
 
 
 def _adapter(params, seed, k=2, scale=0.05):
@@ -48,13 +57,13 @@ def _adapter(params, seed, k=2, scale=0.05):
     return idx, val
 
 
-def _store(params):
-    if "store" not in _CACHE:
+def _store(params, arch="qwen2-1.5b"):
+    if ("store", arch) not in _CACHE:
         store = AdapterStore()
         store.register(*_adapter(params, seed=1))
         store.register(*_adapter(params, seed=2))
-        _CACHE["store"] = store
-    return _CACHE["store"]
+        _CACHE["store", arch] = store
+    return _CACHE["store", arch]
 
 
 _PROMPTS = [[1, 5, 9], list(range(1, 21)), list(range(2, 33)), [1, 7],
@@ -276,3 +285,165 @@ def test_chunked_prefill_kernel_path_on_interpret():
     with ops.use_backend("pallas_interpret"):
         got = go(8)  # chunked through the Pallas kernel
     assert got == want
+
+
+# ---------------------------------------------------- flat row layout
+
+
+def test_chunk_plan_never_exceeds_the_step_rows(monkeypatch):
+    """Over many steps of random arrivals, prompt lengths and output
+    lengths, no plan holds more than ``budget + slots - 1`` real tokens
+    — within the ``chunk_rows`` the mixed step computes — and a plan held
+    to fewer rows than it needs raises instead of dropping tokens."""
+    rng = random.Random(7)
+    for slots, budget in ((5, 8), (32, 256), (3, 1)):
+        rows = chunk_rows(slots, budget)
+        assert rows % 8 == 0 or rows == slots * budget
+        assert budget + slots - 1 <= rows <= slots * budget
+        sched = Scheduler(slots)
+        kv_pos = np.zeros((slots,), np.int32)
+        most = 0
+        for _ in range(300):
+            for _ in range(rng.randrange(3)):
+                sched.submit([1] * rng.randint(1, 3 * budget), max_new=rng.randint(1, 6))
+            sched.admissible()
+            if not sched.has_prefilling():
+                for s, req in enumerate(sched.active):  # one decode step
+                    if req is not None:
+                        req.out.append(1)
+                        kv_pos[s] += 1
+                        if len(req.out) >= req.max_new:
+                            sched.complete(s)
+                continue
+            plan = sched.chunk_plan(budget, kv_pos)
+            real = int(plan["q_len"].sum())
+            assert 0 < real <= budget + slots - 1
+            most = max(most, real)
+            for s, req in enumerate(sched.active):
+                if req is None:
+                    continue
+                kv_pos[s] = plan["q_offset"][s] + plan["q_len"][s]
+                if req.mid_prefill:
+                    req.prefilled += int(plan["q_len"][s])
+                if plan["emit"][s]:
+                    req.out.append(1)
+                    if len(req.out) >= req.max_new:
+                        sched.complete(s)
+        assert most > budget  # prompts and decode tokens shared a step
+        sched.submit([1] * (2 * budget), max_new=1)
+        sched.admissible()
+        while not sched.has_prefilling():
+            sched.complete(next(s for s, r in enumerate(sched.active) if r is not None))
+            sched.admissible()
+        need = int(sched.chunk_plan(budget, kv_pos)["q_len"].sum())
+        with monkeypatch.context() as mp:
+            mp.setattr(scheduler, "chunk_rows", lambda slots, budget: need - 1)
+            with pytest.raises(RuntimeError, match="past its"):
+                sched.chunk_plan(budget, kv_pos)
+
+
+# slot 0 prefills a full chunk at offset 5, slots 1 and 4 decode, slot 2
+# is idle, slot 3 stalled mid-prompt (q_len 0 at offset 6)
+_FLAT_LEN = np.array([8, 1, 0, 0, 1], np.int32)
+_FLAT_OFF = np.array([5, 9, 0, 6, 3], np.int32)
+_FLAT_B, _FLAT_C, _FLAT_MAX, _FLAT_PAGE = 5, 8, 16, 4
+
+
+def _flat_case(case):
+    """A model, its adapters, a cache of random contents and a mixed
+    chunk batch in the layout above, for one row-layout parity case; the
+    ``moe`` cases run reduced phi3.5-moe at its own capacity factor."""
+    arch = "phi3.5-moe-42b-a6.6b" if case.startswith("moe") else "qwen2-1.5b"
+    cfg, m, params = _model(arch)
+    key = jax.random.PRNGKey(11)
+    b, c = _FLAT_B, _FLAT_C
+    n_pages = _FLAT_MAX // _FLAT_PAGE
+    if case == "dense":
+        cache = m.init_cache(b, _FLAT_MAX)
+    else:
+        cache = m.init_paged_cache(
+            b * n_pages, _FLAT_PAGE, kv_dtype="int8" if case == "paged_int8" else "fp32"
+        )
+
+    def fill(i, x):
+        k = jax.random.fold_in(key, i)
+        if x.dtype == jnp.int8:
+            return jax.random.randint(k, x.shape, -127, 128, jnp.int32).astype(jnp.int8)
+        if x.ndim == 4:  # int8 scales
+            return jax.random.uniform(k, x.shape, x.dtype, 0.005, 0.02)
+        return jax.random.normal(k, x.shape, x.dtype)
+
+    cache = {n: fill(i, x) for i, (n, x) in enumerate(sorted(cache.items()))}
+    batch = {
+        "tokens": jax.random.randint(key, (b, c), 1, cfg.vocab_size),
+        "q_offset": jnp.asarray(_FLAT_OFF),
+        "q_len": jnp.asarray(_FLAT_LEN),
+        "last_idx": jnp.asarray(np.maximum(_FLAT_LEN - 1, 0)),
+    }
+    if case != "dense":
+        table = jnp.arange(b * n_pages, dtype=jnp.int32).reshape(b, n_pages)[::-1]
+        batch["block_table"] = batch["write_table"] = table
+    adapters = None
+    if case.endswith("tenants"):
+        aidx, aval = _store(params, arch).stacked()
+        aid = jnp.asarray([1, 2, 0, 1, 2], jnp.int32)
+        aid_l = jnp.broadcast_to(aid[None], (cfg.num_layers, b))
+        adapters = {
+            k: jax.tree.map(
+                lambda i, v, a=(aid_l if k == "blocks" else aid): None
+                if i is None else BatchedDelta(i, v, a),
+                aidx[k], aval[k], is_leaf=lambda x: x is None,
+            )
+            for k in aidx
+        }
+    return cfg, m, params, adapters, cache, batch
+
+
+@pytest.mark.parametrize(
+    "case", ["dense", "paged", "paged_int8", "tenants", "verify", "moe", "moe_tenants"]
+)
+def test_flat_rows_match_the_chunk_grid(case):
+    """The mixed step's dense work on ``rows`` = T < B·C flat rows gives
+    the logits and the cache writes of the (B, C) grid, for a layout with
+    a full prefill chunk at an offset, decode, idle and stalled slots;
+    ``verify_chunk``, whose rows carry no budget, and T = B·C lower to
+    today's grid program unchanged. The flat rows' MoE routing drops no
+    token: at the model's own capacity factor they give what a grid whose
+    experts hold every token gives, where the grid at that factor drops."""
+    cfg, m, params, adapters, cache, batch = _flat_case(
+        "paged" if case == "verify" else case
+    )
+    b, c = _FLAT_B, _FLAT_C
+    rows = chunk_rows(b, c)
+    assert int(_FLAT_LEN.sum()) <= rows < b * c
+    if case == "verify":
+        for fn in (m.verify_chunk, m.prefill_chunk):
+            def lower(extra, fn=fn):
+                return jax.jit(
+                    lambda cache, batch: fn(params, None, cache, {**batch, **extra})
+                ).lower(cache, batch).as_text()
+
+            assert lower({"rows": b * c}) == lower({})
+        return
+
+    def step(model, rows):
+        return jax.jit(
+            lambda cache, batch: model.prefill_chunk(params, adapters, cache, {**batch, "rows": rows})
+        )(cache, batch)
+
+    got_logits, got_cache = step(m, rows)
+    live = _FLAT_LEN > 0  # idle and stalled slots sample nothing
+    if cfg.num_experts:
+        dropping, _ = step(m, None)
+        assert not np.allclose(np.asarray(dropping)[live], np.asarray(got_logits)[live])
+        m = get_model(cfg.replace(capacity_factor=cfg.num_experts / cfg.experts_per_token))
+    want_logits, want_cache = step(m, None)
+    np.testing.assert_allclose(
+        np.asarray(got_logits)[live], np.asarray(want_logits)[live], rtol=1e-5, atol=1e-5
+    )
+    for name in want_cache:
+        np.testing.assert_allclose(
+            np.asarray(got_cache[name], np.float32),
+            np.asarray(want_cache[name], np.float32),
+            rtol=1e-5, atol=1e-5, err_msg=name,
+        )
