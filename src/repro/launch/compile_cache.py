@@ -1,7 +1,7 @@
 """Persistent XLA compile cache for the entry points.
 
 A cold compile of a 28-layer step is tens of seconds, and serving
-compiles one program per megastep variant. The cache key includes the
+compiles one program per step kind. The cache key includes the
 cache directory, so it must sit at a fixed place: the directory that
 ``JAX_COMPILATION_CACHE_DIR`` names when it is set (JAX reads it
 itself), else ``<repo root>/.jax_cache``, derived from this file's

@@ -1,89 +1,53 @@
-"""Multi-tenant batched serving engine — thin orchestration layer.
+"""Multi-tenant batched serving engine — thin host orchestration layer.
 
 The subsystem splits along its natural seams:
 
-* :mod:`repro.serve.scheduler` — FIFO admission, slot assignment, chunk
+* :mod:`repro.serve.scheduler` — admission, slot assignment, chunk
   planning, slot state as dense arrays (host-side, no jax);
 * :mod:`repro.serve.kv_cache`  — the dense slot cache and the paged
   block pool: placement only, every cache write happens in-graph;
-* :mod:`repro.serve.sampler`   — greedy/temperature/top-k sampling fused
-  into the jitted calls;
+* :mod:`repro.serve.steps`     — the compiled steps, device graphs only;
+* :mod:`repro.serve.sampler`   — sampling fused into those steps;
 * :mod:`repro.serve.adapters`  — the tenant registry: N unmerged NeuroAda
-  ``(indices, values)`` trees stacked (and cached) for the batched kernel
-  path.
+  ``(indices, values)`` trees stacked (and cached) for the batched
+  kernel path, so one frozen base serves every tenant.
 
-One frozen base model serves every tenant: each compiled step applies
-each slot's ``(k, d_out)`` delta in-flight via ``ops.delta_apply_batched``
-(jnp oracle or Pallas per-slot gather) instead of merging weights ahead
-of time.
-
-Prefill is **chunked and fused into the serving step** (DESIGN §11): the
-scheduler carves each admitted prompt into ``prefill_chunk``-token
-chunks under a per-step token budget, and while any slot owes prompt
-chunks the engine runs ONE jitted mixed step — decode slots advance one
-token while prefilling slots consume their next chunk, writing k/v
-straight into their cache rows/paged blocks and sampling a first token
-the step their prompt completes. No step runs longer than the budget
-plus one decode token per slot, so a long prompt can no longer stall
-every in-flight stream behind a stop-the-world prefill; and because the
-mixed buffer has ONE compiled shape, the per-pow2-bucket prefill graphs
-(and their splice subsystem) are gone.
-
-Once no prompt chunks are owed, decode runs as a **megastep**: one
-jitted ``lax.scan`` over up to ``decode_chunk`` tokens, carrying (kv
-cache, last tokens, per-slot positions, active mask, max_new budget) as
-device state with sampling, EOS detection, cache advance and per-slot
-masking all in-graph. Every compiled step — mixed or megastep — costs
-exactly ONE device→host transfer; finished slots become masked no-ops
-until the chunk drains, and freed slots re-admit at step boundaries.
-With ``decode_chunk=1`` the megastep reproduces the per-token loop
-exactly (same tokens, same Request lifecycle), so chunking is a pure
-throughput knob (see DESIGN §9).
-
-With ``draft != "off"`` (DESIGN §12) the decode megastep runs
-**speculative** rounds instead of single-token iterations: a cheap
-drafter (quantized self-draft via :mod:`repro.serve.draft`, the merged
-mean-of-tenants model, or the model-free ``ngram`` prompt lookup that
-costs zero draft forwards) proposes ``spec_k`` tokens per slot — a
-model drafter from its own dense KV scratch, ngram from the slot's
-committed token history — the full model scores all k+1 positions as ONE
-verify chunk through the §11 chunk forward, and rejection sampling
-commits a verified prefix — exact greedy token-match on temp-0 slots, so
-greedy outputs are token-identical to plain decode. Rollback is a pure
-per-slot position rewind: step boundaries pre-reserve the
-``decode_chunk × (spec_k + 1)`` horizon, so every row a rejected draft
-wrote is already owned and simply gets overwritten. Still one jitted
-call and ONE device→host transfer per megastep.
+The engine jits ONE function per step kind from
+:func:`repro.serve.steps.build_steps` and dispatches by kind:
+``chunkstep`` while any admitted prompt still owes chunks (DESIGN §11:
+decode slots advance a token while prefilling slots consume their next
+``prefill_chunk`` prompt tokens, one compiled shape for every prompt
+length), else ``megastep`` (DESIGN §9: a ``lax.scan`` over up to
+``decode_chunk`` tokens with sampling, EOS and per-slot masking
+in-graph), or with ``draft != "off"`` (DESIGN §12) ``spec_megastep``
+(model drafter) or ``ngram_megastep`` (prompt lookup). What an engine
+lacks — tenant adapters, paged block tables, a drafter — goes in as
+None, so each engine compiles one program per kind. Every compiled step
+costs exactly ONE device→host transfer; finished slots are masked
+no-ops until the step drains, and freed slots re-admit at step
+boundaries. With ``decode_chunk=1`` the megastep reproduces the
+per-token loop exactly, so chunking is a pure throughput knob.
 
 With ``paged=True`` (DESIGN §10) the dense slot cache becomes a shared
-block pool: capacity is ``num_blocks × page_size`` tokens actually in
-flight, not ``slots × max_len`` reservations. Admission is block-aware
-(a request leaves the queue only when the pool covers its prompt, with
-same-tenant page-aligned prefixes deduplicated against refcounted shared
-blocks), step boundaries pre-reserve every position a compiled body can
-write — preempting the *youngest* request back to the queue head on OOM
-(mid-prefill victims included: they re-prefill over ``prompt + out``
-later and continue identically) — and both the read and write block
-tables ride the compiled steps as device state.
+block pool: admission is block-aware (same-tenant page-aligned prefixes
+dedup against refcounted shared blocks), and step boundaries
+pre-reserve every position a compiled step can write — the speculative
+horizon included, which makes rollback a position rewind — preempting
+the *youngest* request back to the queue head on OOM.
 
 Observability (DESIGN §13) is host-side by construction: every counter,
-gauge, histogram and trace span derives from state a compiled step
-already hands back in its one device→host bundle (emitted tokens,
-positions, survivor masks, acceptance counts) or from pure host
-bookkeeping (queue depth, pool free-list, wall clocks). Instrumentation
-therefore cannot change the ONE-transfer-per-megastep contract — the
-transfer-counting tests run with metrics and tracing enabled — and it
-adds no traced inputs, so the compiled graphs are byte-identical with
-observability on or off (the compile-count regression test pins that).
-``metrics=False`` swaps in the no-op registry; ``tracer=None`` (the
-default) skips lifecycle tracing entirely. ``step`` also marks its
-phases (expire, admit, reserve, plan, put, dispatch, fetch, emit,
-gauges) with profiler spans (``repro.obs.spans``), recorded whenever a
-profiler runs and about a microsecond each when none does.
+gauge, histogram and trace span derives from the bundle a compiled step
+already hands back or from host bookkeeping, so it cannot change the
+one-transfer contract and adds no traced inputs (the compile-count
+regression test pins that). ``metrics=False`` swaps in the no-op
+registry; ``tracer=None`` (the default) skips lifecycle tracing.
+``step`` marks its phases (expire, admit, reserve, plan, put, dispatch,
+fetch, emit, gauges) with profiler spans (``repro.obs.spans``).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -91,7 +55,6 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.obs.clock as _clock
-from repro.core.delta import BatchedDelta
 from repro.obs import MetricsRegistry, NullRegistry, Tracer
 from repro.obs import spans
 from repro.obs.spans import span
@@ -106,6 +69,7 @@ from repro.serve.scheduler import (
     Scheduler,
     chunk_rows,
 )
+from repro.serve.steps import build_steps
 
 __all__ = [
     "QueueFullError",
@@ -219,20 +183,8 @@ class ServeEngine:
             # top. quant_block must match the base the adapters were
             # trained against (launch --quant-block).
             params = quantize_base(params, base_dtype, block=quant_block)
-        if mesh is not None:
-            # Megatron placement over the frozen (possibly packed) base:
-            # col-parallel qkv/up, row-parallel o/down, vocab-sharded
-            # embed/head; QuantizedTensor leaves fit the spec to their
-            # packed data/scales children. fsdp=False — serving shards for
-            # compute, never for optimizer-state capacity.
-            from repro.distributed.sharding import param_shardings
-
-            params = jax.device_put(
-                params,
-                param_shardings(params, mesh, model.cfg.family, fsdp=False),
-            )
         self.model = model
-        self.params = params
+        self.params = self._place(params)
         self.slots = slots
         self.max_len = max_len
         self.eos_id = eos_id
@@ -320,18 +272,9 @@ class ServeEngine:
         # scheme) or the merged mean-of-tenants model — and keeps its own
         # dense KV scratch advanced lock-step with the verified frontier.
         if draft in ("int8", "nf4", "merged"):
-            self.draft_params = build_draft_params(
+            self.draft_params = self._place(build_draft_params(
                 self.params, draft, store=adapter_store, quant_block=quant_block
-            )
-            if mesh is not None:
-                from repro.distributed.sharding import param_shardings
-
-                self.draft_params = jax.device_put(
-                    self.draft_params,
-                    param_shardings(
-                        self.draft_params, mesh, model.cfg.family, fsdp=False
-                    ),
-                )
+            ))
             self.draft_kv = DraftKVCache(model, slots, max_len, mesh=mesh)
         else:
             # off, or the model-free ngram drafter: no params, no scratch —
@@ -339,520 +282,50 @@ class ServeEngine:
             self.draft_params = None
             self.draft_kv = None
 
-        L = model.cfg.num_layers
-        eos, mlen, chunk = eos_id, max_len, decode_chunk
         # the mixed step's dense work runs on this many flat token rows,
         # the most a chunk plan can fill, not on the (slots, C) buffer
-        self.chunk_rows = rows = chunk_rows(slots, self.prefill_chunk)
+        self.chunk_rows = chunk_rows(slots, self.prefill_chunk)
+        steps = build_steps(
+            model, lambda: self.sampler, eos_id=eos_id, max_len=max_len,
+            decode_chunk=decode_chunk, spec_k=spec_k, rows=self.chunk_rows,
+        )
 
-        def batched_adapters(aidx, aval, aid):
-            # blocks leaves ride the layer scan: their aid copy carries a
-            # leading L axis so scan slices every xs leaf uniformly.
-            aid_l = jnp.broadcast_to(aid[None, :], (L, aid.shape[0]))
-            out = {}
-            for key, sub_i in aidx.items():
-                a = aid_l if key == "blocks" else aid
-                out[key] = jax.tree.map(
-                    lambda i, v, a=a: None if i is None else BatchedDelta(i, v, a),
-                    sub_i, aval[key], is_leaf=lambda x: x is None,
-                )
-            return out
-
-        def chunkstep(p, adapters, table, wtable, cache, tokens, q_offset,
-                      q_len, last_idx, temps, key):
-            """Compiled mixed prefill+decode step (DESIGN §11).
-
-            One (slots, prefill_chunk) token buffer: prefilling slots
-            carry their next prompt chunk, decode slots the degenerate
-            one-token chunk, idle/stalled slots ``q_len = 0`` no-ops.
-            K/v land in-graph (write table gates shared paged blocks),
-            logits gather at each row's last real token, sampling is
-            fused — the (slots,) token vector is the step's single host
-            transfer. Positions advance to ``q_offset + q_len`` for
-            every role (decode +1, prefill +take, idle frozen).
-            """
-            batch = {"tokens": tokens, "q_offset": q_offset,
-                     "q_len": q_len, "last_idx": last_idx, "rows": rows}
-            if table is not None:
-                batch["block_table"] = table
-                batch["write_table"] = wtable
-            logits, cache = model.prefill_chunk(p, adapters, cache, batch)
-            toks = self.sampler(logits, temps, key)
-            return cache, q_offset + q_len, toks
-
-        def chunkstep_plain(p, cache, *args):
-            return chunkstep(p, None, None, None, cache, *args)
-
-        def chunkstep_ad(p, aidx, aval, aid, cache, *args):
-            adapters = batched_adapters(aidx, aval, aid)
-            return chunkstep(p, adapters, None, None, cache, *args)
-
-        def chunkstep_paged_plain(p, table, wtable, cache, *args):
-            return chunkstep(p, None, table, wtable, cache, *args)
-
-        def chunkstep_paged_ad(p, aidx, aval, aid, table, wtable, cache, *args):
-            adapters = batched_adapters(aidx, aval, aid)
-            return chunkstep(p, adapters, table, wtable, cache, *args)
-
-        def megastep(p, adapters, table, cache, tok, pos, active, remaining,
-                     temps, key):
-            """Compiled decode loop over up to ``chunk`` tokens.
-
-            Device-state carry: (cache, last tokens, per-slot pos, active
-            mask, max_new budget). Finished/empty slots are masked no-ops:
-            their token and position freeze, and their cache writes land on
-            a stale row (dense) or their own already-reserved page (paged)
-            that the overwrite-before-attend invariant makes unobservable —
-            empty paged slots carry sentinel table rows, so their writes
-            drop entirely. ``table`` (paged engines) is device state for
-            the whole chunk: chunk boundaries pre-reserve every position
-            the loop can write, so no allocation happens in-graph. Ys: the
-            (chunk, slots) emitted-token matrix plus its emit mask — the
-            step's single host transfer.
-            """
-
-            def body(carry, k_t):
-                cache, tok, pos, active, remaining = carry
-                batch = {"token": tok, "pos": pos}
-                if table is not None:
-                    batch["block_table"] = table
-                logits, cache = model.decode_step(p, adapters, cache, batch)
-                nxt = self.sampler(logits, temps, k_t)
-                emitted = active
-                tok = jnp.where(active, nxt, tok)
-                pos = jnp.where(active, pos + 1, pos)
-                remaining = jnp.where(active, remaining - 1, remaining)
-                # mirror of the host Request lifecycle: EOS | max_new | cache
-                # full — evaluated post-advance, exactly like _maybe_finish
-                active = (
-                    active & (tok != eos) & (remaining > 0) & (pos < mlen - 1)
-                )
-                return (cache, tok, pos, active, remaining), (tok, emitted)
-
-            keys = jax.random.split(key, chunk)
-            (cache, tok, pos, active, remaining), (toks, emits) = jax.lax.scan(
-                body, (cache, tok, pos, active, remaining), keys
-            )
-            return cache, pos, active, toks, emits
-
-        def megastep_plain(p, cache, tok, pos, active, remaining, temps, key):
-            return megastep(
-                p, None, None, cache, tok, pos, active, remaining, temps, key
-            )
-
-        def megastep_ad(
-            p, aidx, aval, aid, cache, tok, pos, active, remaining, temps, key
-        ):
-            adapters = batched_adapters(aidx, aval, aid)
-            return megastep(
-                p, adapters, None, cache, tok, pos, active, remaining, temps, key
-            )
-
-        def megastep_paged_plain(
-            p, table, cache, tok, pos, active, remaining, temps, key
-        ):
-            return megastep(
-                p, None, table, cache, tok, pos, active, remaining, temps, key
-            )
-
-        def megastep_paged_ad(
-            p, aidx, aval, aid, table, cache, tok, pos, active, remaining,
-            temps, key,
-        ):
-            adapters = batched_adapters(aidx, aval, aid)
-            return megastep(
-                p, adapters, table, cache, tok, pos, active, remaining, temps,
-                key,
-            )
-
-        K = spec_k
-
-        def spec_chunkstep(p, dp, adapters, table, wtable, cache, dcache,
-                           tokens, q_offset, q_len, last_idx, temps, key):
-            """Mixed prefill+decode step with the drafter riding along.
-
-            The drafter consumes the SAME (slots, C) token buffer into its
-            own dense KV scratch — its logits are dead code XLA prunes, so
-            drafting adds one cache-write pass to prefill, not a second
-            head. Still one compiled call, one host transfer: by the time
-            decode starts, the drafter's cache mirrors every verified
-            position (prefix-share fast-forward is disabled under
-            drafting for exactly this reason — see ``_try_place``).
-            """
-            batch = {"tokens": tokens, "q_offset": q_offset,
-                     "q_len": q_len, "last_idx": last_idx, "rows": rows}
-            if table is not None:
-                batch["block_table"] = table
-                batch["write_table"] = wtable
-            logits, cache = model.prefill_chunk(p, adapters, cache, batch)
-            dbatch = {"tokens": tokens, "q_offset": q_offset,
-                      "q_len": q_len, "last_idx": last_idx, "rows": rows}
-            _, dcache = model.prefill_chunk(dp, None, dcache, dbatch)
-            toks = self.sampler(logits, temps, key)
-            return cache, dcache, q_offset + q_len, toks
-
-        def spec_chunkstep_plain(p, dp, cache, dcache, *args):
-            return spec_chunkstep(p, dp, None, None, None, cache, dcache, *args)
-
-        def spec_chunkstep_ad(p, dp, aidx, aval, aid, cache, dcache, *args):
-            adapters = batched_adapters(aidx, aval, aid)
-            return spec_chunkstep(
-                p, dp, adapters, None, None, cache, dcache, *args
-            )
-
-        def spec_chunkstep_paged_plain(p, dp, table, wtable, cache, dcache,
-                                       *args):
-            return spec_chunkstep(
-                p, dp, None, table, wtable, cache, dcache, *args
-            )
-
-        def spec_chunkstep_paged_ad(p, dp, aidx, aval, aid, table, wtable,
-                                    cache, dcache, *args):
-            adapters = batched_adapters(aidx, aval, aid)
-            return spec_chunkstep(
-                p, dp, adapters, table, wtable, cache, dcache, *args
-            )
-
-        def spec_verify_round(p, adapters, table, cache, tok, pos, active,
-                              remaining, temps, d_t, q_t, k_acc, k_res):
-            """Shared verify/accept/commit half of one speculative round
-            (DESIGN §12), drafter-agnostic: takes the (S, K) proposals
-            ``d_t`` and their drafter distributions ``q_t`` from whichever
-            drafter produced them.
-
-            ``q_t`` is the drafter's (S, K, V) distribution tensor, or
-            ``None`` for a deterministic drafter (ngram): a deterministic
-            proposal's distribution is the one-hot δ_d, so q(d) ≡ 1 and
-            the gather is skipped — the accept rule degenerates to
-            u < p(d) and the residual max(0, p − δ_d) to p with the d
-            column zeroed.
-
-            (1) The full model scores [tok, d_1..d_K] as ONE verify chunk —
-            k/v for all K+1 positions land in pre-reserved rows/pages in
-            the same pass; q_len clamps at the cache edge so no row writes
-            past max_len (emission never reaches the clamped rows: the
-            cache-full trigger fires first), and paged writes go through
-            the READ table — verify rows are decode-region positions the
-            slot owns, never shared prefix pages. (2) Standard rejection
-            sampling accepts a prefix (u·q(d) < p(d), exact greedy
-            token-match when temp = 0 via one-hot distributions), the
-            first rejection resamples from max(0, p−q), a full accept
-            draws the bonus from row K. (3) The host-lifecycle stop
-            conditions (EOS | max_new | cache full) replay per emitted
-            token, truncating the commit at the first trigger exactly
-            where the per-token loop stops. Rollback is a per-slot ``pos``
-            advance of n_emit ≤ K+1: the rejected suffix's rows sit beyond
-            the new frontier in rows the slot already owns, unobservable
-            until overwritten — no table edit, no allocation, no
-            device→host traffic.
-            """
-            C = K + 1
-            S = d_t.shape[0]
-            ctokens = jnp.concatenate([tok[:, None], d_t], axis=1)
-            q_len = jnp.where(active, jnp.minimum(C, mlen - pos), 0)
-            vbatch = {"tokens": ctokens, "q_offset": pos, "q_len": q_len}
-            if table is not None:
-                vbatch["block_table"] = table
-                vbatch["write_table"] = table
-            vlogits, cache = model.verify_chunk(p, adapters, cache, vbatch)
-            p_t = self.sampler.probs(
-                vlogits.reshape(S * C, -1), jnp.repeat(temps, C)
-            ).reshape(S, C, -1)  # target distribution at every position
-
-            # rejection-sample an accepted prefix: a = |accepted|
-            u = jax.random.uniform(k_acc, (S, K))
-            p_d = jnp.take_along_axis(p_t[:, :K], d_t[..., None], -1)[..., 0]
-            if q_t is None:
-                acc = u < p_d  # q(d) ≡ 1 for a deterministic drafter
-            else:
-                q_d = jnp.take_along_axis(q_t, d_t[..., None], -1)[..., 0]
-                acc = u * jnp.maximum(q_d, 1e-30) < p_d
-            a = jnp.sum(jnp.cumprod(acc.astype(jnp.int32), axis=1), axis=1)
-
-            # ONE replacement draw per slot, from row a — only the first
-            # rejected column's residual is ever consumed, and at a full
-            # accept (a = K) row K *is* the bonus row, so a single (S, V)
-            # categorical replaces the per-column (S, K, V) machinery. The
-            # residual max(0, p−q) normalised (equal dists degenerate to
-            # p); q one-hot means p with the d column zeroed.
-            rows = jnp.arange(S)
-            p_sel = p_t[rows, a]
-            if q_t is None:
-                # scatter 0 at the rejected proposal; a = K drops (no-op)
-                d_rej = jnp.where(
-                    a < K, d_t[rows, jnp.minimum(a, K - 1)], p_t.shape[-1]
-                )
-                res = p_sel.at[rows, d_rej].set(0.0, mode="drop")
-            else:
-                q_sel = jnp.where(
-                    (a < K)[:, None], q_t[rows, jnp.minimum(a, K - 1)], 0.0
-                )
-                res = jnp.maximum(p_sel - q_sel, 0.0)
-            res = jnp.where(
-                jnp.sum(res, axis=-1, keepdims=True) > 0, res, p_sel
-            )
-            repl = jax.random.categorical(k_res, jnp.log(res)).astype(
-                jnp.int32
-            )
-
-            # candidate stream: accepted drafts then the correction
-            idxs = jnp.arange(C)[None, :]
-            d_pad = jnp.concatenate(
-                [d_t, jnp.zeros((S, 1), jnp.int32)], axis=1
-            )
-            cand = jnp.where(idxs < a[:, None], d_pad, repl[:, None])
-            # stop triggers replayed per emitted token, post-advance — the
-            # same EOS | max_new | cache-full order as the per-token body;
-            # the triggering token IS emitted, then everything after it in
-            # the round is rolled back too
-            j1 = idxs + 1
-            trig = (
-                (cand == eos)
-                | (remaining[:, None] - j1 <= 0)
-                | (pos[:, None] + j1 >= mlen - 1)
-            )
-            can = (idxs <= a[:, None]) & active[:, None]
-            hit = can & trig
-            before = jnp.cumsum(hit.astype(jnp.int32), axis=1)
-            emit = can & (before - hit.astype(jnp.int32) == 0)
-            n_emit = jnp.sum(emit.astype(jnp.int32), axis=1)
-
-            last = jnp.take_along_axis(
-                cand, jnp.maximum(n_emit - 1, 0)[:, None], axis=1
-            )[:, 0]
-            tok = jnp.where(n_emit > 0, last, tok)
-            pos = pos + n_emit  # the rollback: rejected rows sit beyond
-            remaining = remaining - n_emit
-            active = active & ~jnp.any(hit & emit, axis=1)
-            return cache, tok, pos, active, remaining, (cand, emit, a)
-
-        def spec_megastep(p, dp, adapters, table, cache, dcache, tok, pos,
-                          active, remaining, temps, key):
-            """Compiled speculative decode loop: ``chunk`` draft/verify
-            rounds per call (DESIGN §12), model drafter.
-
-            Each round, the drafter runs K+1 one-token steps from the
-            verified frontier, proposing d_1..d_K and recording its
-            sampling distribution per proposal (the K+1-th step only
-            back-fills d_K's k/v so an all-accept round leaves no hole),
-            then hands them to the shared verify/accept/commit half. Ys
-            per round: (slots, K+1) candidate tokens + emit mask,
-            acceptance counts, and the round-entry live mask — with the
-            final positions and survivor mask, the megastep's single host
-            transfer.
-            """
-
-            def round_body(carry, k_t):
-                cache, dcache, tok, pos, active, remaining = carry
-                live = active
-                k_draft, k_acc, k_res = jax.random.split(k_t, 3)
-
-                def draft_body(c, k_i):
-                    dcache, dtok, dpos = c
-                    dl, dcache = model.decode_step(
-                        dp, None, dcache, {"token": dtok, "pos": dpos}
-                    )
-                    p_d = self.sampler.probs(dl, temps)
-                    nxt = self.sampler(dl, temps, k_i)
-                    return (dcache, nxt, dpos + 1), (nxt, p_d)
-
-                (dcache, _, _), (drafts, p_draft) = jax.lax.scan(
-                    draft_body, (dcache, tok, pos),
-                    jax.random.split(k_draft, K + 1),
-                )
-                d_t = drafts[:K].T  # (S, K); the K+1-th is cache-fill only
-                q_t = p_draft[:K].transpose(1, 0, 2)  # (S, K, V)
-                cache, tok, pos, active, remaining, ys = spec_verify_round(
-                    p, adapters, table, cache, tok, pos, active, remaining,
-                    temps, d_t, q_t, k_acc, k_res,
-                )
-                return (
-                    (cache, dcache, tok, pos, active, remaining),
-                    (*ys, live),
-                )
-
-            keys = jax.random.split(key, chunk)
-            (cache, dcache, tok, pos, active, remaining), ys = jax.lax.scan(
-                round_body, (cache, dcache, tok, pos, active, remaining), keys
-            )
-            toks, emits, accs, lives = ys
-            return cache, dcache, pos, active, toks, emits, accs, lives
-
-        def ngram_megastep(p, adapters, table, cache, hist, tok, pos,
-                           active, remaining, temps, key):
-            """Compiled speculative decode loop, model-free ngram drafter
-            (prompt lookup, DESIGN §12): drafting costs ZERO forwards, so
-            a round is one batched verify pass for up to K+1 tokens.
-
-            ``hist`` is the (slots, max_len) committed token sequence
-            (prompt + emitted), aligned with ``pos``: hist[s, pos[s]] is
-            the slot's current input token. Each round matches the most
-            recent *earlier* occurrence j of the current token and
-            proposes the continuation hist[j+1..] — wrapped with period
-            pos − j where it runs past the frontier, so a period-p cycle
-            (the attractor greedy decode settles into) extrapolates to a
-            full K-token window instead of stalling at the p known
-            followers. Deterministic proposal → the drafter distribution
-            is a one-hot, so the accept rule degenerates to u < p(d) on
-            sampled rows and exact token-match on greedy rows; the output
-            distribution stays exactly the target's. Committed tokens
-            append to hist in-graph, so later rounds in the same call
-            match against them too. No match proposes token 0 — it simply
-            gets rejected and the round still emits the verified
-            correction.
-            """
-            idx_h = jnp.arange(mlen)
-
-            def round_body(carry, k_t):
-                cache, hist, tok, pos, active, remaining = carry
-                live = active
-                k_acc, k_res = jax.random.split(k_t)
-                # most recent j < pos with hist[j] == current token; the
-                # continuation hist[j+1 .. j+K] wraps with period pos − j
-                # past the frontier: a period-p cycle's nearest match sits
-                # only p back with p known followers, and the wrap
-                # extrapolates the cycle to the full K-token window
-                eq = (hist == tok[:, None]) & (idx_h[None, :] < pos[:, None])
-                j = jnp.max(jnp.where(eq, idx_h[None, :], -1), axis=1)
-                period = jnp.maximum(pos - j, 1)
-                cols = j[:, None] + 1 + jnp.mod(
-                    jnp.arange(K)[None, :], period[:, None]
-                )
-                d_t = jnp.where(
-                    (j >= 0)[:, None],
-                    jnp.take_along_axis(
-                        hist, jnp.clip(cols, 0, mlen - 1), axis=1
-                    ),
-                    0,
-                )
-                pos0 = pos
-                cache, tok, pos, active, remaining, ys = spec_verify_round(
-                    p, adapters, table, cache, tok, pos, active, remaining,
-                    temps, d_t, None, k_acc, k_res,
-                )
-                cand, emit, a = ys
-                # append the committed tokens at pos0+1.. so later rounds
-                # (and the next match) see them; non-emitted columns drop
-                S = d_t.shape[0]
-                wpos = jnp.where(
-                    emit, pos0[:, None] + 1 + jnp.arange(K + 1)[None, :], mlen
-                )
-                hist = hist.at[jnp.arange(S)[:, None], wpos].set(
-                    cand, mode="drop"
-                )
-                return (
-                    (cache, hist, tok, pos, active, remaining),
-                    (*ys, live),
-                )
-
-            keys = jax.random.split(key, chunk)
-            (cache, hist, tok, pos, active, remaining), ys = jax.lax.scan(
-                round_body, (cache, hist, tok, pos, active, remaining), keys
-            )
-            toks, emits, accs, lives = ys
-            return cache, pos, active, toks, emits, accs, lives
-
-        def spec_megastep_plain(p, dp, cache, dcache, *args):
-            return spec_megastep(p, dp, None, None, cache, dcache, *args)
-
-        def spec_megastep_ad(p, dp, aidx, aval, aid, cache, dcache, *args):
-            adapters = batched_adapters(aidx, aval, aid)
-            return spec_megastep(p, dp, adapters, None, cache, dcache, *args)
-
-        def spec_megastep_paged_plain(p, dp, table, cache, dcache, *args):
-            return spec_megastep(p, dp, None, table, cache, dcache, *args)
-
-        def spec_megastep_paged_ad(p, dp, aidx, aval, aid, table, cache,
-                                   dcache, *args):
-            adapters = batched_adapters(aidx, aval, aid)
-            return spec_megastep(p, dp, adapters, table, cache, dcache, *args)
-
-        def ngram_megastep_plain(p, cache, hist, *args):
-            return ngram_megastep(p, None, None, cache, hist, *args)
-
-        def ngram_megastep_ad(p, aidx, aval, aid, cache, hist, *args):
-            adapters = batched_adapters(aidx, aval, aid)
-            return ngram_megastep(p, adapters, None, cache, hist, *args)
-
-        def ngram_megastep_paged_plain(p, table, cache, hist, *args):
-            return ngram_megastep(p, None, table, cache, hist, *args)
-
-        def ngram_megastep_paged_ad(p, aidx, aval, aid, table, cache, hist,
-                                    *args):
-            adapters = batched_adapters(aidx, aval, aid)
-            return ngram_megastep(p, adapters, table, cache, hist, *args)
-
-        # every compiled step function registers here by name: the jit
-        # caches are the source of truth for compile counting
-        # (``compile_counts`` sums their entry counts — a cache that grows
-        # after warmup is a recompile regression).
+        # one jitted function per step kind; ``compile_counts`` reads their
+        # jit caches, and a cache that grows after warmup is a recompile
         self._jitted: dict[str, object] = {}
 
-        def _jit(name, fn):
-            j = jax.jit(fn)
-            self._jitted[name] = j
-            if mesh is None:
-                return j
+        def _jit(name):
+            # a sharded engine calls each step inside _sharded_call's scope
+            j = self._jitted[name] = jax.jit(steps[name])
+            return j if mesh is None else functools.partial(self._sharded_call, j)
 
-            # sharded engine: every compiled call runs inside a SCOPED
-            # sharding context (serve mesh + TP activation layout),
-            # snapshot/restored around the call — a tp=1 engine or a
-            # trainer in the same process must never observe this state
-            def call(*args, _j=j):
-                return self._sharded_call(_j, *args)
-
-            return call
-
-        self._chunkstep_plain = _jit("chunkstep_plain", chunkstep_plain)
-        self._chunkstep_ad = _jit("chunkstep_ad", chunkstep_ad)
-        self._chunkstep_paged_plain = _jit(
-            "chunkstep_paged_plain", chunkstep_paged_plain
-        )
-        self._chunkstep_paged_ad = _jit("chunkstep_paged_ad", chunkstep_paged_ad)
-        self._megastep_plain = _jit("megastep_plain", megastep_plain)
-        self._megastep_ad = _jit("megastep_ad", megastep_ad)
-        self._megastep_paged_plain = _jit(
-            "megastep_paged_plain", megastep_paged_plain
-        )
-        self._megastep_paged_ad = _jit("megastep_paged_ad", megastep_paged_ad)
+        self._chunkstep = _jit("chunkstep")
+        self._megastep = _jit("megastep")
         if draft == "ngram":
-            # model-free drafter: no drafter cache to feed, so mixed
-            # prefill+decode steps stay on the PLAIN chunkstep graphs —
-            # only the decode megastep family is speculative
-            self._ngram_megastep_plain = _jit(
-                "ngram_megastep_plain", ngram_megastep_plain
-            )
-            self._ngram_megastep_ad = _jit("ngram_megastep_ad", ngram_megastep_ad)
-            self._ngram_megastep_paged_plain = _jit(
-                "ngram_megastep_paged_plain", ngram_megastep_paged_plain
-            )
-            self._ngram_megastep_paged_ad = _jit(
-                "ngram_megastep_paged_ad", ngram_megastep_paged_ad
-            )
+            # model-free drafter: no drafter cache to feed, so the mixed
+            # step runs without one; only decode is speculative
+            self._ngram_megastep = _jit("ngram_megastep")
         elif draft != "off":
-            self._spec_chunkstep_plain = _jit(
-                "spec_chunkstep_plain", spec_chunkstep_plain
-            )
-            self._spec_chunkstep_ad = _jit("spec_chunkstep_ad", spec_chunkstep_ad)
-            self._spec_chunkstep_paged_plain = _jit(
-                "spec_chunkstep_paged_plain", spec_chunkstep_paged_plain
-            )
-            self._spec_chunkstep_paged_ad = _jit(
-                "spec_chunkstep_paged_ad", spec_chunkstep_paged_ad
-            )
-            self._spec_megastep_plain = _jit(
-                "spec_megastep_plain", spec_megastep_plain
-            )
-            self._spec_megastep_ad = _jit("spec_megastep_ad", spec_megastep_ad)
-            self._spec_megastep_paged_plain = _jit(
-                "spec_megastep_paged_plain", spec_megastep_paged_plain
-            )
-            self._spec_megastep_paged_ad = _jit(
-                "spec_megastep_paged_ad", spec_megastep_paged_ad
-            )
+            self._spec_megastep = _jit("spec_megastep")
         self._obs_init()
 
     # ------------------------------------------------- sharded dispatch
+
+    def _place(self, tree):
+        """Megatron placement of a frozen (possibly packed) param tree
+        over the serving mesh: col-parallel qkv/up, row-parallel o/down,
+        vocab-sharded embed/head; QuantizedTensor leaves fit the spec to
+        their packed data/scales children. fsdp=False — serving shards
+        for compute, never for optimizer-state capacity. No mesh: the
+        tree unchanged."""
+        if self.mesh is None:
+            return tree
+        from repro.distributed.sharding import param_shardings
+
+        shardings = param_shardings(
+            tree, self.mesh, self.model.cfg.family, fsdp=False
+        )
+        return jax.device_put(tree, shardings)
 
     def _sharded_call(self, fn, *args):
         """Run one compiled step inside the TP sharding scope.
@@ -875,6 +348,14 @@ class ServeEngine:
                 return fn(*args)
         finally:
             dist_ctx.restore(snap)
+
+    @staticmethod
+    def _adapter_args(stacked, aid):
+        """The ``(aidx, aval, aid)`` triple a compiled step takes for its
+        tenant deltas, or None on an engine without a store."""
+        if stacked is None:
+            return None
+        return (*stacked, jnp.asarray(aid))
 
     def _stacked(self):
         """The tenant stacks, placed for this engine's mesh (tp=1: the
@@ -1079,16 +560,12 @@ class ServeEngine:
             self._scraped_prefix = (hits, fresh)
 
     def compile_counts(self) -> dict[str, int]:
-        """Per-step-function jit cache sizes. Every entry is one traced
-        compilation; a steady-state engine compiles each live variant
-        once, so totals must be flat across steps after warmup (the
-        regression test drives mixed, decode and spec steps and asserts
-        exactly that)."""
-        out = {}
-        for name, fn in self._jitted.items():
-            size = getattr(fn, "_cache_size", None)
-            out[name] = int(size()) if size is not None else 0
-        return out
+        """Jit cache size per registered step kind. Every entry is one
+        traced compilation; a steady-state engine compiles each kind it
+        runs once, so the counts must be flat across steps after warmup
+        (the regression test drives mixed, decode and spec steps and
+        asserts exactly that)."""
+        return {name: int(j._cache_size()) for name, j in self._jitted.items()}
 
     def compile_count(self) -> int:
         return sum(self.compile_counts().values())
@@ -1481,30 +958,21 @@ class ServeEngine:
                 counts["kv_pages"] = int((-(-vl // self.kv.page_size)).sum())
                 counts["kv_pages_grid"] = self.kv.table.size
             sp.set_metadata(**counts)
-        spec = self.draft_kv is not None  # ngram prefills like plain
+        # a model drafter's scratch rides the mixed step; ngram has none
+        dcache = self.draft_kv.data if self.draft_kv is not None else None
         with span(spans.SERVE_PUT):
-            lead = [self.params]
-            if spec:
-                lead.append(self.draft_params)
-            if stacked is not None:
-                lead += [*stacked, jnp.asarray(plan["aid"])]
-            if self.paged:
-                lead += [self.kv.table_device(), self.kv.write_table_device()]
+            adapters = self._adapter_args(stacked, plan["aid"])
+            table = self.kv.table_device() if self.paged else None
+            wtable = self.kv.write_table_device() if self.paged else None
             inputs = [jnp.asarray(plan[k]) for k in
                       ("tokens", "q_offset", "q_len", "last_idx", "temps")]
-        caches = [self.kv.data, self.draft_kv.data] if spec else [self.kv.data]
-        fn = getattr(
-            self,
-            ("_spec_chunkstep" if spec else "_chunkstep")
-            + ("_paged" if self.paged else "")
-            + ("_ad" if stacked is not None else "_plain"),
-        )
         with span(spans.SERVE_DISPATCH):
-            out = fn(*lead, *caches, *inputs, key)
-        if spec:
-            self.kv.data, self.draft_kv.data, pos_dev, toks_dev = out
-        else:
-            self.kv.data, pos_dev, toks_dev = out
+            self.kv.data, dcache, pos_dev, toks_dev = self._chunkstep(
+                self.params, self.draft_params, adapters, table, wtable,
+                self.kv.data, dcache, *inputs, key,
+            )
+        if self.draft_kv is not None:
+            self.draft_kv.data = dcache
         # ONE device→host transfer for the whole mixed step: the sampled
         # token vector. Positions advance deterministically to
         # q_offset + q_len, so the host mirrors them without a fetch.
@@ -1601,6 +1069,18 @@ class ServeEngine:
 
     # ---------------------------------------------------- decode megastep
 
+    def _megastep_args(self, st, stacked, key):
+        """The tenant triple, read table and slot-state inputs that both
+        decode megasteps take, uploaded (call inside ``serve.put``)."""
+        adapters = self._adapter_args(stacked, st["aid"])
+        table = self.kv.table_device() if self.paged else None
+        inputs = (
+            jnp.asarray(st["tokens"]), self.kv.pos,
+            jnp.asarray(st["active"]), jnp.asarray(st["remaining"]),
+            jnp.asarray(st["temps"]), key,
+        )
+        return adapters, table, inputs
+
     def _decode_step(self, key) -> None:
         """One decode megastep over all active slots: up to
         ``decode_chunk`` tokens per slot in one compiled call."""
@@ -1612,28 +1092,16 @@ class ServeEngine:
             st = self.scheduler.slot_arrays()
             stacked = self._stacked()
         with span(spans.SERVE_PUT):
-            args = (
-                self.kv.data, jnp.asarray(st["tokens"]), self.kv.pos,
-                jnp.asarray(st["active"]), jnp.asarray(st["remaining"]),
-                jnp.asarray(st["temps"]), key,
-            )
-            if self.paged:
-                args = (self.kv.table_device(),) + args
-            if stacked is not None:
-                args = (*stacked, jnp.asarray(st["aid"])) + args
-        fn = getattr(
-            self,
-            "_megastep"
-            + ("_paged" if self.paged else "")
-            + ("_ad" if stacked is not None else "_plain"),
-        )
+            adapters, table, inputs = self._megastep_args(st, stacked, key)
         with span(spans.SERVE_DISPATCH):
-            out = fn(self.params, *args)
-        self.kv.data, pos_dev = out[0], out[1]
+            self.kv.data, *fetched = self._megastep(
+                self.params, adapters, table, self.kv.data, *inputs
+            )
+        pos_dev = fetched[0]
         # ONE device→host transfer for the whole chunk (all slots, all
         # steps): emitted tokens + mask, final positions, survivor mask.
         with span(spans.SERVE_FETCH):
-            pos_np, active_np, toks, emits = jax.device_get(out[1:])
+            pos_np, active_np, toks, emits = jax.device_get(fetched)
         with span(spans.SERVE_EMIT) as sp:
             self._c_transfers.inc()
             now = self.clock()
@@ -1687,34 +1155,20 @@ class ServeEngine:
                         seq = req.prompt + req.out
                         hist[s, : len(seq)] = seq
         with span(spans.SERVE_PUT):
-            lead = [self.params] if ngram else [self.params, self.draft_params]
-            if stacked is not None:
-                lead += [*stacked, jnp.asarray(st["aid"])]
-            if self.paged:
-                lead.append(self.kv.table_device())
+            adapters, table, inputs = self._megastep_args(st, stacked, key)
             if ngram:
-                caches = [self.kv.data, jnp.asarray(hist)]
-            else:
-                caches = [self.kv.data, self.draft_kv.data]
-            inputs = (
-                jnp.asarray(st["tokens"]), self.kv.pos,
-                jnp.asarray(st["active"]), jnp.asarray(st["remaining"]),
-                jnp.asarray(st["temps"]), key,
-            )
-        fn = getattr(
-            self,
-            ("_ngram_megastep" if ngram else "_spec_megastep")
-            + ("_paged" if self.paged else "")
-            + ("_ad" if stacked is not None else "_plain"),
-        )
+                hist = jnp.asarray(hist)
         with span(spans.SERVE_DISPATCH):
-            out = fn(*lead, *caches, *inputs)
-        if ngram:
-            self.kv.data, pos_dev = out[0], out[1]
-            fetched = out[1:]
-        else:
-            self.kv.data, self.draft_kv.data, pos_dev = out[0], out[1], out[2]
-            fetched = out[2:]
+            if ngram:
+                self.kv.data, *fetched = self._ngram_megastep(
+                    self.params, adapters, table, self.kv.data, hist, *inputs
+                )
+            else:
+                self.kv.data, self.draft_kv.data, *fetched = self._spec_megastep(
+                    self.params, self.draft_params, adapters, table,
+                    self.kv.data, self.draft_kv.data, *inputs,
+                )
+        pos_dev = fetched[0]
         # still ONE device→host transfer for the whole megastep: positions,
         # survivor mask, candidate tokens + emit mask, acceptance counts,
         # round-entry live masks — one fetch of the bundle

@@ -119,12 +119,9 @@ def test_unified_step_compiles_once_per_mode():
         for p in _PROMPTS:  # lengths 2..32: four pow2 buckets at min 16
             eng.submit(p, max_new=3)
         eng.run_to_completion()
-        chunkstep = (
-            eng._chunkstep_paged_plain if paged else eng._chunkstep_plain
-        )
-        megastep = eng._megastep_paged_plain if paged else eng._megastep_plain
-        assert chunkstep._cache_size() == 1
-        assert megastep._cache_size() == 1
+        counts = eng.compile_counts()
+        assert counts["chunkstep"] == 1
+        assert counts["megastep"] == 1
         # adapter-mode twin: one more compile, not one per bucket
         eng2 = ServeEngine(m, params, slots=2, max_len=64, eos_id=_NO_EOS,
                            decode_chunk=2, prefill_chunk=8, paged=paged,
@@ -132,10 +129,7 @@ def test_unified_step_compiles_once_per_mode():
         for p in _PROMPTS:
             eng2.submit(p, max_new=3, adapter_id=1)
         eng2.run_to_completion()
-        chunkstep_ad = (
-            eng2._chunkstep_paged_ad if paged else eng2._chunkstep_ad
-        )
-        assert chunkstep_ad._cache_size() == 1
+        assert eng2.compile_counts()["chunkstep"] == 1
 
 
 # --------------------------------------------------- transfer accounting

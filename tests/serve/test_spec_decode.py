@@ -200,9 +200,9 @@ def test_spec_sampling_matches_target_distribution():
     n = 400
     counts = np.zeros(cfg.vocab_size)
     for i in range(n):
-        out = eng._spec_megastep_plain(
-            eng.params, eng.draft_params, eng.kv.data, eng.draft_kv.data,
-            *args, jax.random.PRNGKey(i),
+        out = eng._spec_megastep(
+            eng.params, eng.draft_params, None, None, eng.kv.data,
+            eng.draft_kv.data, *args, jax.random.PRNGKey(i),
         )
         toks, emits = np.asarray(out[4]), np.asarray(out[5])
         assert emits[0, 0, 0]  # an active slot emits >= 1 token per round
@@ -288,8 +288,8 @@ def test_ngram_sampling_matches_target_distribution():
     n = 400
     counts = np.zeros(cfg.vocab_size)
     for i in range(n):
-        out = eng._ngram_megastep_plain(
-            eng.params, eng.kv.data, *args, jax.random.PRNGKey(i)
+        out = eng._ngram_megastep(
+            eng.params, None, None, eng.kv.data, *args, jax.random.PRNGKey(i)
         )
         toks, emits = np.asarray(out[3]), np.asarray(out[4])
         assert emits[0, 0, 0]
